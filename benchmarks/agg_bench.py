@@ -19,7 +19,9 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.common import emit, timeit
-from repro.perf.hlo_stats import HBM_BW
+from repro.perf.hlo_stats import peaks
+
+HBM_BW = peaks("TPU v5 lite")["hbm_bw"]
 
 
 def _combine_bench(quick: bool):

@@ -12,7 +12,10 @@ import jax.numpy as jnp
 
 from benchmarks.common import emit, timeit
 from repro.kernels import ref
-from repro.perf.hlo_stats import HBM_BW, PEAK_FLOPS_BF16
+from repro.perf.hlo_stats import peaks
+
+_V5E = peaks("TPU v5 lite")
+HBM_BW, PEAK_FLOPS_BF16 = _V5E["hbm_bw"], _V5E["bf16_flops"]
 
 
 def bench_kernels():

@@ -29,6 +29,7 @@ from repro.cluster.simulator import ChurnEvent, ChurnSim, ClusterSim
 from repro.configs.base import get_config
 from repro.core.controller import ElfvingController
 from repro.data.pipeline import SyntheticTokens
+from repro.launch.compile_cache import place_compile_cache
 from repro.launch.train import Trainer, jit_train_step
 from repro.models import model as M
 
@@ -67,6 +68,7 @@ def make_trainer(cfg, n_workers, timer):
 
 
 def main():
+    place_compile_cache()
     shutil.rmtree(CKPT, ignore_errors=True)
     cfg = get_config("qwen2-0.5b").reduced()
 

@@ -13,11 +13,13 @@ tick) to show the scheduler policies' throughput trade-offs.
 import numpy as np
 
 from repro.cluster.simulator import ChurnEvent
+from repro.launch.compile_cache import place_compile_cache
 from repro.launch.multi_job import build_multi_job, run_ticks
 from repro.ps import make_scheduler
 
 
 def main():
+    place_compile_cache()
     ticks = 36
     kill_at, back_at = ticks // 3, 2 * ticks // 3
 

@@ -16,11 +16,13 @@ from repro.configs.base import get_config
 from repro.core.controller import CutoffController
 from repro.core.runtime_model.api import RuntimeModel
 from repro.data.pipeline import SyntheticTokens
+from repro.launch.compile_cache import place_compile_cache
 from repro.launch.train import Trainer, jit_train_step
 from repro.models import model as M
 
 
 def main():
+    place_compile_cache()
     n_workers = 8
     cfg = get_config("qwen2-0.5b").reduced()
 

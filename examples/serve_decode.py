@@ -11,11 +11,13 @@ import jax
 import numpy as np
 
 from repro.configs.base import get_config
+from repro.launch.compile_cache import place_compile_cache
 from repro.models import model as M
 from repro.serving.engine import ServeEngine
 
 
 def main():
+    place_compile_cache()
     for name in ["qwen2-0.5b", "xlstm-350m", "hymba-1.5b"]:
         cfg = get_config(name).reduced()
         params = M.init_model(cfg, jax.random.PRNGKey(0))

@@ -20,6 +20,7 @@ from repro.configs.base import ArchConfig, get_config
 from repro.core.controller import CutoffController, FullSyncController
 from repro.core.runtime_model.api import RuntimeModel
 from repro.data.pipeline import SyntheticTokens
+from repro.launch.compile_cache import place_compile_cache
 from repro.launch.train import Trainer, jit_train_step
 from repro.models import model as M
 
@@ -51,6 +52,7 @@ def main():
                          "metrics JSONL) under this directory; render "
                          "with: python -m repro.obs <dir>")
     args = ap.parse_args()
+    place_compile_cache()
 
     cfg = model_100m()
     n_params = cfg.n_params()

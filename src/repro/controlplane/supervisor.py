@@ -191,7 +191,9 @@ class ProcWorkerPool:
         src = os.path.join(os.path.dirname(__file__), "..", "..")
         env["PYTHONPATH"] = (os.path.abspath(src)
                              + os.pathsep + env.get("PYTHONPATH", ""))
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # workers only heartbeat and checkpoint: keep them off the chip,
+        # which belongs to the one process that trains
+        env["JAX_PLATFORMS"] = "cpu"
         p = subprocess.Popen(args, env=env,
                              stdout=subprocess.DEVNULL,
                              stderr=subprocess.DEVNULL)

@@ -28,8 +28,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.dist import compat  # noqa: F401  (installs jax.shard_map on 0.4.x)
-
 
 def example_weights(mask: np.ndarray, global_batch: int) -> np.ndarray:
     """Expand a per-worker bit array to per-example weights.

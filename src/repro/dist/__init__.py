@@ -3,8 +3,6 @@
 ``repro.dist.sharding`` (imported everywhere as ``shd``) is the single
 source of truth for how logical axes (dp / sp / tp) map onto mesh axes in
 each execution mode; ``repro.dist.collectives`` carries the cutoff-SGD
-bit-array aggregation behind the same layout.  Importing this package also
-installs the gated JAX compatibility polyfills (``repro.dist.compat``).
+bit-array aggregation behind the same layout.
 """
-from repro.dist import compat  # noqa: F401  (installs jax polyfills)
 from repro.dist import collectives, sharding  # noqa: F401

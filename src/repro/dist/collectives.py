@@ -27,16 +27,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import aggregation
 from repro.dist import sharding as shd
-
-# NOTE: repro.core.aggregation is imported lazily inside the functions —
-# it imports repro.dist.compat for the shard_map polyfill, so a module-level
-# import here would be circular.
 
 
 def example_weights(mask: np.ndarray, global_batch: int) -> np.ndarray:
     """Per-worker bit array -> per-example loss weights (production path)."""
-    from repro.core import aggregation
     return aggregation.example_weights(mask, global_batch)
 
 
@@ -53,7 +49,6 @@ def masked_grad_mean(grads, mask_bit, lay: Optional[shd.Layout] = None):
     if lay.mesh is None or not lay.dp:
         from repro.kernels import ops
         return ops.masked_aggregate_tree(grads, jnp.asarray(mask_bit))
-    from repro.core import aggregation
     return aggregation.masked_psum_mean(grads, mask_bit, lay.mesh, lay.dp)
 
 

@@ -42,9 +42,8 @@ from dataclasses import dataclass
 from typing import Optional, Sequence, Tuple
 
 import jax
+from jax._src.lax.parallel import all_gather_invariant
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from repro.dist import compat  # noqa: F401  (installs jax polyfills)
 
 MODES = ("local", "train_sp", "train_fsdp", "decode_tp")
 
@@ -217,8 +216,10 @@ def use_weight(tree):
     sharding constraint ("wsc") — XLA inserts the use-site all-gather and
     transposes it to a reduce-scatter of the weight gradients; under
     ``knobs().fsdp_gather == "shardmap"`` an explicit shard_map all-gather
-    over the model axis (dim-0-sharded leaves only) with the same
-    reduce-scatter AD transpose.
+    over the model axis (dim-0-sharded leaves only).  That gather is
+    ``all_gather_invariant`` (varying in, replicated out, as
+    ``out_specs=P()`` states); its AD transpose slices each shard's rows
+    out of the reduced weight cotangent.
     """
     lay = layout()
     if lay.mesh is None or lay.mode not in ("train_sp", "train_fsdp"):
@@ -232,8 +233,10 @@ def use_weight(tree):
             return leaf
         if (impl == "shardmap" and m is not None and tp > 1
                 and leaf.shape[0] % tp == 0):
+            # varying -> invariant gather: the output really is the same
+            # on every model shard, which is what out_specs=P() asserts
             def body(w_l):
-                return jax.lax.all_gather(w_l, m, axis=0, tiled=True)
+                return all_gather_invariant(w_l, m, axis=0, tiled=True)
             return jax.shard_map(body, mesh=mesh, in_specs=P(m),
                                  out_specs=P())(leaf)
         return jax.lax.with_sharding_constraint(

@@ -1,4 +1,3 @@
 # OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
 # for compute hot-spots the paper itself optimizes with a custom
 # kernel. Leave this package empty if the paper has none.
-from repro.dist import compat as _compat  # noqa: F401  (pallas polyfills)

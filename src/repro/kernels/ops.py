@@ -1,11 +1,10 @@
 """jit'd public wrappers around the Pallas kernels.
 
-``KERNEL_BACKEND`` picks the execution path:
-  * "pallas"    — real TPU lowering (production)
+``KERNEL_BACKEND`` (env ``REPRO_KERNEL_BACKEND``) picks the execution path:
+  * "pallas"    — real TPU lowering
   * "interpret" — Pallas interpret mode (CPU validation; used by tests)
-  * "xla"       — the pure-jnp reference (this container's default runtime)
-
-The model stack calls these wrappers so the TPU deployment flips one flag.
+  * "xla"       — the pure-jnp reference
+  * unset       — "pallas" when JAX's default backend is a TPU, else "xla"
 """
 from __future__ import annotations
 
@@ -21,11 +20,13 @@ from repro.kernels import masked_grad_agg as _ma
 from repro.kernels import mlstm_chunk as _ml
 from repro.kernels import ref
 
-KERNEL_BACKEND = os.environ.get("REPRO_KERNEL_BACKEND", "xla")
+KERNEL_BACKEND = os.environ.get("REPRO_KERNEL_BACKEND")
 
 
 def _mode():
-    return KERNEL_BACKEND
+    if KERNEL_BACKEND:
+        return KERNEL_BACKEND
+    return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
 def attention(q, k, v, *, causal=True, window=0):

@@ -33,6 +33,9 @@ from repro.models import model as M
 
 from repro.perf import hlo_stats
 
+# the chip of the v5e pods the production meshes describe
+TARGET_KIND = "TPU v5 lite"
+
 OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                        "experiments", "dryrun")
 
@@ -345,7 +348,8 @@ def run_cell(cfg, shape, mesh, mesh_name: str, out_dir: str,
             "collective_bytes_per_device": acc["coll"],
             "collectives_by_kind": acc["coll_by_kind"],
             "roofline": hlo_stats.roofline_terms(
-                acc["flops"], acc["bytes"], acc["coll"]),
+                acc["flops"], acc["bytes"], acc["coll"],
+                device_kind=TARGET_KIND),
         })
         print(f"[OK]   {cfg.name:24s} {shape.name:12s} {mesh_name:10s} "
               f"compile={t2-t1:6.1f}s flops/dev={acc['flops']:.3e} "

@@ -14,7 +14,8 @@ import json
 import time
 
 from repro.configs.base import SHAPES, get_config
-from repro.launch.dryrun import account_cell, grad_accum_for, lower_cell, zero1_for
+from repro.launch.dryrun import (TARGET_KIND, account_cell, grad_accum_for,
+                                 lower_cell, zero1_for)
 from repro.launch.mesh import make_production_mesh
 from repro.perf import hlo_stats
 from repro.perf.knobs import Knobs, use_knobs
@@ -56,8 +57,9 @@ def measure(arch, shape_name, *, mesh=None, knob_kw=None, grad_accum=None,
             "bytes_per_device": acc["bytes"],
             "collective_bytes_per_device": acc["coll"],
             "collectives_by_kind": acc["coll_by_kind"],
-            "roofline": hlo_stats.roofline_terms(acc["flops"], acc["bytes"],
-                                                 acc["coll"]),
+            "roofline": hlo_stats.roofline_terms(
+                acc["flops"], acc["bytes"], acc["coll"],
+                device_kind=TARGET_KIND),
             "measure_s": round(time.time() - t0, 1),
         }
         if with_memory:
@@ -159,7 +161,8 @@ def kernel_adjusted(arch, shape_name, *, mesh=None, knob_kw=None,
     rec["bytes_per_device_kernel_adjusted"] = adj
     rec["xla_attn_bytes_per_score_elem"] = k_per
     rec["roofline_kernel_adjusted"] = hlo_stats.roofline_terms(
-        base["flops_per_device"], adj, base["collective_bytes_per_device"])
+        base["flops_per_device"], adj, base["collective_bytes_per_device"],
+        device_kind=TARGET_KIND)
     return rec
 
 

@@ -7,16 +7,16 @@ shards over it, model sharding never does.
 """
 from __future__ import annotations
 
-from repro.dist.compat import AxisType, make_mesh as _make_mesh
+import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes,
-                      axis_types=(AxisType.Auto,) * len(axes))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    return _make_mesh(tuple(shape), tuple(axes),
-                      axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))

@@ -146,7 +146,9 @@ def attn_core(q, k, v, qpos, kpos, *, causal=True, window=0, softcap=0.0,
         outs = [one((qs[i], qps[i])) for i in range(n)]
         out = jnp.stack(outs, axis=0)
     else:
-        out = jax.lax.map(one, (qs, qps))
+        # remat per chunk: without it the backward keeps every chunk's
+        # (qc, Sk) scores at once, i.e. the full (Sq, Sk) matrix
+        out = jax.lax.map(jax.checkpoint(one, prevent_cse=False), (qs, qps))
     return out.swapaxes(0, 1).reshape(B, Sq, H, hd)
 
 

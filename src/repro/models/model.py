@@ -364,14 +364,16 @@ def chunked_ce_sum(cfg, params, x, labels, weights, vchunk: int):
     """Vocab-chunked fused CE for the local / train_fsdp layouts.
 
     Streams the head in (D, vchunk) slices with running (max, sum-exp,
-    label-logit) accumulators — the (T, V) logits tensor never materializes
-    (peak extra memory = one (T, vchunk) fp32 tile + the gathered head).
+    label-logit) accumulators — the (T, V) logits tensor never materializes,
+    forward or backward (peak extra memory = one (T, vchunk) fp32 tile +
+    the gathered head).
     """
     tied = cfg.tie_embeddings
     w = params["embed"]["table"] if tied else params["lm_head"]["w"]
     w = shd.use_weight(w)
     B, S, D = x.shape
     V = cfg.vocab_size
+    vchunk = min(vchunk, V)
     nch = -(-V // vchunk)
     xf = x.reshape(-1, D)
     labf = labels.reshape(-1)
@@ -379,7 +381,10 @@ def chunked_ce_sum(cfg, params, x, labels, weights, vchunk: int):
 
     def body(carry, i):
         m_run, s_run, ll = carry
-        off = i * vchunk
+        start = i * vchunk
+        # the last chunk is read from V - vchunk when vchunk does not
+        # divide V; its columns below ``start`` were already counted
+        off = jnp.minimum(start, V - vchunk)
         if tied:
             w_c = jax.lax.dynamic_slice(w, (off, 0), (vchunk, D))
             logits = jax.lax.dot_general(xf, w_c, (((1,), (1,)), ((), ())),
@@ -388,14 +393,13 @@ def chunked_ce_sum(cfg, params, x, labels, weights, vchunk: int):
             w_c = jax.lax.dynamic_slice(w, (0, off), (D, vchunk))
             logits = jax.lax.dot_general(xf, w_c, (((1,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32)
-        # mask pad columns when vchunk does not divide V
         col = off + jnp.arange(vchunk)
-        logits = jnp.where(col[None, :] < V, logits, -1e30)
+        logits = jnp.where(col[None, :] >= start, logits, -1e30)
         m_new = jnp.maximum(m_run, jnp.max(logits, axis=-1))
         s_run = (s_run * jnp.exp(m_run - m_new)
                  + jnp.sum(jnp.exp(logits - m_new[:, None]), axis=-1))
         rel = labf - off
-        inr = (rel >= 0) & (rel < vchunk)
+        inr = (labf >= start) & (rel < vchunk)
         pick = jnp.take_along_axis(
             logits, jnp.clip(rel, 0, vchunk - 1)[:, None], axis=1)[:, 0]
         ll = jnp.where(inr, pick, ll)
@@ -403,7 +407,10 @@ def chunked_ce_sum(cfg, params, x, labels, weights, vchunk: int):
 
     init = (jnp.full((T,), -1e30, jnp.float32), jnp.zeros((T,), jnp.float32),
             jnp.zeros((T,), jnp.float32))
-    (m_run, s_run, ll), _ = jax.lax.scan(body, init, jnp.arange(nch))
+    # remat per chunk: the backward recomputes each (T, vchunk) tile
+    # instead of saving all of them, which would be the full logits
+    (m_run, s_run, ll), _ = jax.lax.scan(
+        jax.checkpoint(body, prevent_cse=False), init, jnp.arange(nch))
     ce = (m_run + jnp.log(jnp.maximum(s_run, 1e-30))) - ll
     if weights is not None:
         wt = jnp.broadcast_to(weights.astype(jnp.float32)[:, None],

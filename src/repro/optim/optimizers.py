@@ -77,10 +77,10 @@ def adam(lr: Schedule, b1: float = 0.9, b2: float = 0.999,
          fused: bool = False) -> Optimizer:
     """Adam(W).  ``fused=True`` routes the whole moment-and-param update
     through ``kernels.ops.adam_update_tree`` — the Pallas one-HBM-pass
-    kernel on TPU, with the pure-jnp reference under the default ``"xla"``
-    kernel backend.  Matches the unfused path allclose (the fused kernel
-    computes p' directly, so the returned "update" is p' - p up to one
-    rounding)."""
+    kernel on TPU, with the pure-jnp reference under the ``"xla"`` kernel
+    backend (the default off the TPU).  Matches the unfused path allclose
+    (the fused kernel computes p' directly, so the returned "update" is
+    p' - p up to one rounding)."""
     def init(params):
         z = lambda p: jnp.zeros_like(p, dtype=jnp.float32)
         return {"step": jnp.zeros((), jnp.int32),

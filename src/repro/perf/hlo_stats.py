@@ -59,19 +59,34 @@ def collective_bytes(hlo_text: str) -> Dict[str, int]:
 
 
 # ---------------------------------------------------------------------------
-# Roofline terms (TPU v5e).
+# Roofline terms, from published per-chip peaks.
 # ---------------------------------------------------------------------------
 
-PEAK_FLOPS_BF16 = 197e12      # per chip
-HBM_BW = 819e9                # bytes/s per chip
-ICI_BW = 50e9                 # bytes/s per link (~per chip effective)
+# Keyed by ``jax.Device.device_kind``.  Source: Google Cloud documentation,
+# "TPU v5e" (per-chip specifications): 197 TFLOP/s bf16, 393 TOP/s int8,
+# 16 GB HBM at 819 GB/s, 1,600 Gbit/s inter-chip interconnect over 4 links.
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bw": 819e9,
+                    "ici_link_bw": 1600e9 / 8 / 4},
+}
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """Published peaks of one chip of ``device_kind``; unknown kinds raise."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}"
+                       f" (known: {sorted(PEAKS)})") from None
 
 
 def roofline_terms(flops_per_dev: float, bytes_per_dev: float,
-                   coll_bytes_per_dev: float) -> Dict[str, float]:
-    t_c = flops_per_dev / PEAK_FLOPS_BF16
-    t_m = bytes_per_dev / HBM_BW
-    t_n = coll_bytes_per_dev / ICI_BW
+                   coll_bytes_per_dev: float, *,
+                   device_kind: str) -> Dict[str, float]:
+    pk = peaks(device_kind)
+    t_c = flops_per_dev / pk["bf16_flops"]
+    t_m = bytes_per_dev / pk["hbm_bw"]
+    t_n = coll_bytes_per_dev / pk["ici_link_bw"]
     dom = max(("compute", t_c), ("memory", t_m), ("collective", t_n),
               key=lambda kv: kv[1])[0]
     return {"compute_s": t_c, "memory_s": t_m, "collective_s": t_n,

@@ -67,3 +67,23 @@ def test_grad_accum_matches(arch_name):
     # documented, standard semantic of microbatched MoE training.
     tol = 2e-3 if cfg.n_experts else 2e-5
     assert err < tol, err
+
+
+@pytest.mark.parametrize("tied", [True, False])
+@pytest.mark.parametrize("vocab,vchunk", [(300, 128), (256, 128)])
+def test_chunked_ce_matches_dense(vocab, vchunk, tied):
+    """Vocab-chunked CE == dense CE, including a last chunk that overhangs
+    V (read from V - vchunk, its already-counted columns masked)."""
+    import dataclasses
+    cfg = dataclasses.replace(reduced_cfg("qwen2-0.5b"), vocab_size=vocab,
+                              tie_embeddings=tied)
+    key = jax.random.PRNGKey(3)
+    params = M.init_model(cfg, key)
+    batch = tiny_batch(cfg, key, B=2, S=16)
+    # labels in the overhang's re-read columns and in the last chunk
+    labels = batch["labels"].at[0, :3].set(jnp.array([vocab - 1, 130, 200]))
+    weights = jnp.array([1.0, 0.5])
+    x, _, _ = M.forward(cfg, params, batch, mode="train", head=False)
+    dense = M._ce_sum_dense(M.lm_logits(cfg, params, x), labels, weights)
+    chunked = M.chunked_ce_sum(cfg, params, x, labels, weights, vchunk)
+    assert float(chunked) == pytest.approx(float(dense), rel=1e-5)
