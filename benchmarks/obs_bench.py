@@ -4,7 +4,7 @@ Four sections, all seeded, emitted as CSV rows AND into
 ``BENCH_obs.json`` (schema ``bench_obs/v1``):
 
   * ``step`` — the headline gate: end-to-end Trainer step latency with a
-    full ``ObsRun`` attached (step/predict/dispatch/observe spans, one
+    full ``ObsRun`` attached (the step's spans recorded, one
     donated metric-ring push per step, the decision-quality wrapper)
     vs the identical bare trainer, at n ∈ {8, 158}.  Min-of-repeats on
     both sides; ``scripts/ci.sh --bench`` pins ``overhead_frac`` at
@@ -13,9 +13,10 @@ Four sections, all seeded, emitted as CSV rows AND into
     ``MetricRing.push`` (one donated jit dispatch, nothing fetched) and
     per ``MetricsRegistry.drain`` of a full 256-row ring (the ONLY
     device read the spine ever does).
-  * ``span`` — µs per tracer span (two ``perf_counter`` stamps + one
-    in-memory record), and that cost multiplied by the 4 spans a
-    Trainer step emits.
+  * ``span`` — µs per span recorded by an open tracer (two
+    ``perf_counter`` stamps + one in-memory record), that cost multiplied
+    by the 7 spans a Trainer step emits, and ns per span with nobody
+    listening (the shared no-op object).
   * ``calibration`` — a seeded controller-level mini-race (sync /
     static / firstk / dmm over the same paper-cluster draws) recorded
     through ``--obs-dir`` artifacts, then summarized with
@@ -90,7 +91,10 @@ def _step_bench(n_list, steps: int, repeats: int = 3):
             tr.run(3)                       # warm the compile caches
             t0 = time.perf_counter()
             tr.run(steps)
-            return (time.perf_counter() - t0) / steps * 1e6
+            us = (time.perf_counter() - t0) / steps * 1e6
+            if obs is not None:
+                obs.close()
+            return us
 
         bare = min(run_once(False) for _ in range(repeats))
         inst = min(run_once(True) for _ in range(repeats))
@@ -133,18 +137,30 @@ def _ring_bench(n_push: int = 512):
 
 
 def _span_bench(n_spans: int = 4000):
-    from repro.obs.trace import ObsLog, Tracer
+    from repro.obs.trace import ObsLog, Tracer, span
 
+    # nobody listening: the shared no-op object
+    t0 = time.perf_counter()
+    for _ in range(n_spans):
+        with span("bench.span"):
+            pass
+    ns_off = (time.perf_counter() - t0) / n_spans * 1e9
     tracer = Tracer(log=ObsLog(None))
     t0 = time.perf_counter()
     for i in range(n_spans):
-        with tracer.span("bench.span", track="bench", step=i):
+        with span("bench.span", step=i):
             pass
     us = (time.perf_counter() - t0) / n_spans * 1e6
-    # a Trainer step opens 4 spans: trainer.step + predict/dispatch/observe
-    out = {"n_spans": n_spans, "us_per_span": us,
-           "spans_per_trainer_step": 4, "us_per_trainer_step": 4 * us}
-    emit("obs/span", us, f"{4 * us:.1f}us/trainer-step")
+    tracer.close()
+    # a DMM Trainer step opens 7 spans: trainer.step, trainer.timer,
+    # trainer.batch, train.dispatch, controller.predict_cutoff (+ its
+    # controller.fetch) and controller.observe
+    per_step = 7
+    out = {"n_spans": n_spans, "us_per_span": us, "ns_per_span_off": ns_off,
+           "spans_per_trainer_step": per_step,
+           "us_per_trainer_step": per_step * us}
+    emit("obs/span", us, f"{per_step * us:.1f}us/trainer-step;"
+         f"off={ns_off:.0f}ns")
     return out
 
 

@@ -36,7 +36,6 @@ import os
 import signal
 import subprocess
 import sys
-from contextlib import nullcontext
 from typing import Dict, List, Optional
 
 import numpy as np
@@ -44,6 +43,7 @@ import numpy as np
 from repro.controlplane.events import Event, EventLog
 from repro.controlplane.faults import FaultInjector
 from repro.controlplane.heartbeat import DEAD, HeartbeatMonitor
+from repro.obs.trace import span
 
 
 # ---------------------------------------------------------------------------
@@ -296,9 +296,8 @@ class Supervisor:
                  log: Optional[EventLog] = None, start_tick: int = 0,
                  obs=None):
         self.pool = pool
-        # optional repro.obs.ObsRun: tick spans are host perf_counter
-        # edges + host counters only — tick() is a lint hot root, and
-        # nothing here ever touches a device value
+        # optional repro.obs.ObsRun: host counters only — tick() is a
+        # lint hot root, and nothing here ever touches a device value
         self.obs = obs
         self.log = log if log is not None else EventLog()
         self.monitor = HeartbeatMonitor(
@@ -325,10 +324,7 @@ class Supervisor:
     def tick(self, tick: int) -> bool:
         """One control-plane step; returns True if membership changed."""
         tick = int(tick)
-        span = (self.obs.trace.span("supervisor.tick", track="controlplane",
-                                    tick=tick)
-                if self.obs is not None else nullcontext())
-        with span:
+        with span("supervisor.tick", tick=tick):
             self.pool.pump(tick, self.monitor, self.log)
             for wid, _old, new in self.monitor.advance(tick):
                 if new == DEAD:

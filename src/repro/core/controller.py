@@ -48,6 +48,7 @@ import numpy as np
 
 from repro.core.cutoff import censoring, elfving, order_stats
 from repro.core.runtime_model.api import (RuntimeModel, colwise_uniform)
+from repro.obs.trace import span
 
 
 class FullSyncController:
@@ -652,6 +653,10 @@ class CutoffController:
 
     # -- decision -------------------------------------------------------
     def predict_cutoff(self) -> int:
+        with span("controller.predict_cutoff"):
+            return self._predict_cutoff()
+
+    def _predict_cutoff(self) -> int:
         self._step += 1
         if not self.warmed_up:
             self._pending_pred = None
@@ -682,8 +687,10 @@ class CutoffController:
         self._pending_decision = None
         self._pending_pred = (pred_mu, pred_std, samples)
         self._last_iter = pred_iter          # device scalar, fetched lazily
-        # the ONLY host/device sync on the decision path: one int32
-        return int(cutoff)
+        # the ONLY host/device sync on the decision path: one int32, which
+        # waits for the decision queued behind the train step
+        with span("controller.fetch"):
+            return int(cutoff)
 
     def predicted_samples(self):
         """The predictive sample cloud (K, n) behind the decision just
@@ -731,6 +738,10 @@ class CutoffController:
 
     # -- observation ----------------------------------------------------
     def observe(self, times, finished_mask=None):
+        with span("controller.observe"):
+            self._observe(times, finished_mask)
+
+    def _observe(self, times, finished_mask):
         if finished_mask is not None and not bool(np.any(finished_mask)):
             # no coherent cutoff time exists: the device path would
             # silently impute at max(where(False, ..)) = -inf and poison

@@ -25,6 +25,8 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from repro.obs.trace import span
+
 
 @dataclass
 class JobRun:
@@ -110,8 +112,6 @@ def run_ticks(server, jobs: Dict[str, JobRun], scheduler, ticks: int, *,
     """The multi-tenant hot loop: schedule -> prefetch -> serve -> flush.
 
     Returns per-tick service lists plus aggregate counters."""
-    from contextlib import nullcontext
-
     from repro.ps.scheduler import job_views
 
     obs = getattr(server, "obs", None)
@@ -119,9 +119,7 @@ def run_ticks(server, jobs: Dict[str, JobRun], scheduler, ticks: int, *,
     serviced = {job_id: 0 for job_id in jobs}
     d0 = server.dispatches
     for tick in range(ticks):
-        span = (obs.trace.span("multi_job.tick", track="driver", tick=tick)
-                if obs is not None else nullcontext())
-        with span:
+        with span("multi_job.tick", tick=tick):
             order = scheduler.order(job_views(server), capacity)
             server.prefetch(order)
             for job_id in order:

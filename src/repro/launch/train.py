@@ -39,6 +39,7 @@ from repro import optim
 from repro.dist import collectives
 from repro.dist import sharding as shd
 from repro.models import model as M
+from repro.obs.trace import span
 
 
 # ---------------------------------------------------------------------------
@@ -453,10 +454,11 @@ class Trainer:
     metrics_every: int = 10
 
     # telemetry (optional): an ``repro.obs.ObsRun``.  Attaching one adds
-    # spans around the step phases, one device metric-ring push per step,
-    # and forwards drained history records to the obs step stream — and
-    # NOTHING else: decisions, RNG streams and parameters stay
-    # bit-identical with obs on or off (tests/test_obs.py pins this).
+    # one device metric-ring push per step and forwards drained history
+    # records to the obs step stream (the step's spans are there either
+    # way; an open ObsRun records them) — and NOTHING else: decisions,
+    # RNG streams and parameters stay bit-identical with obs on or off
+    # (tests/test_obs.py pins this).
     obs: Any = None
     name: Optional[str] = None                # job/run label for obs streams
 
@@ -604,46 +606,36 @@ class Trainer:
             # the obs drain rides the same boundary as the loss fetch:
             # decision scoring + device metric rings come back here, and
             # ONLY here — never inside the step
-            with self.obs.trace.span("obs.drain", track="trainer",
-                                     step=self.step):
+            with span("obs.drain", step=self.step):
                 self.obs.drain()
 
     def run(self, n_steps: int, *, eval_fn=None, eval_every: int = 0,
             verbose: bool = False):
-        from contextlib import nullcontext
         from repro.checkpoint import store
         ckpt = (store.AsyncCheckpointer(self.ckpt_dir, self.keep)
                 if self.ckpt_dir else None)
-        null = nullcontext()
-        tracer = self.obs.trace if self.obs is not None else None
         ring = (self.obs.metrics.ring(
             "trainer" if self.name is None else f"trainer[{self.name}]",
             ("loss", "gnorm", "c", "iter_time"))
             if self.obs is not None else None)
         for _ in range(n_steps):
-            step_span = (tracer.span("trainer.step", track="trainer",
-                                     step=self.step + 1, job=self.name)
-                         if tracer is not None else null)
-            with step_span:
+            with span("trainer.step", step=self.step + 1, job=self.name):
                 self._sync_membership()  # elastic: follow the timer's width
                 n = self.n_workers
-                with (tracer.span("controller.predict_cutoff",
-                                  track="trainer")
-                      if tracer is not None else null):
-                    c = int(self.controller.predict_cutoff())
-                c = min(c, n)
-                times = (self.timer.step() if self.timer is not None
-                         else np.ones(n))
-                # fastest c workers participate (the PS's bit array)
-                order = np.argsort(times)
-                mask = np.zeros(n, np.float32)
-                mask[order[:c]] = 1.0
-                iter_time = float(times[order[c - 1]])
-                # the controller must see the SAME worker set the
-                # aggregation used: under ties, a times<=iter_time
-                # threshold marks MORE than c workers finished and the
-                # two views diverge
-                finished = mask.astype(bool)
+                c = min(int(self.controller.predict_cutoff()), n)
+                with span("trainer.timer"):
+                    times = (self.timer.step() if self.timer is not None
+                             else np.ones(n))
+                    # fastest c workers participate (the PS's bit array)
+                    order = np.argsort(times)
+                    mask = np.zeros(n, np.float32)
+                    mask[order[:c]] = 1.0
+                    iter_time = float(times[order[c - 1]])
+                    # the controller must see the SAME worker set the
+                    # aggregation used: under ties, a times<=iter_time
+                    # threshold marks MORE than c workers finished and
+                    # the two views diverge
+                    finished = mask.astype(bool)
 
                 # anytime policy: stragglers contribute their completed
                 # fraction instead of a zeroed bit; finishers stay 1.0
@@ -652,12 +644,13 @@ class Trainer:
                     contrib = np.asarray(
                         self.controller.contribution(times, c), np.float32)
 
-                batch = dict(self.data.batch(self.step))
-                if self.mask_agg == "psum":
-                    batch["mask"] = jnp.asarray(contrib)
-                else:
-                    batch["weights"] = collectives.example_weights(
-                        contrib, batch["tokens"].shape[0])
+                with span("trainer.batch"):
+                    batch = dict(self.data.batch(self.step))
+                    if self.mask_agg == "psum":
+                        batch["mask"] = jnp.asarray(contrib)
+                    else:
+                        batch["weights"] = collectives.example_weights(
+                            contrib, batch["tokens"].shape[0])
                 decay = getattr(self.controller, "stale_decay", None)
                 if decay is not None:
                     if self.mask_agg != "psum":
@@ -678,8 +671,7 @@ class Trainer:
                 # dispatch the train step FIRST (async), then run the
                 # PS's observe/imputation so controller inference
                 # overlaps compute
-                with (tracer.span("train.dispatch", track="trainer")
-                      if tracer is not None else null):
+                with span("train.dispatch"):
                     self.state, metrics = self.step_fn(self.state, batch)
                 if decay is not None:
                     if "stale" not in metrics:
@@ -689,9 +681,7 @@ class Trainer:
                             "stale_reuse=True) — this one returned no "
                             "metrics['stale'] buffer")
                     self._stale = metrics.pop("stale")
-                with (tracer.span("controller.observe", track="trainer")
-                      if tracer is not None else null):
-                    self.controller.observe(times, finished)
+                self.controller.observe(times, finished)
                 self.step += 1
                 self.sim_clock += iter_time
                 rec = {"step": self.step, "clock": self.sim_clock, "c": c,

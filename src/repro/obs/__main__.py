@@ -1,15 +1,15 @@
 """CLI: render a recorded run's timeline + calibration report.
 
-  PYTHONPATH=src python -m repro.obs OBS_DIR [--chrome trace.json]
+  PYTHONPATH=src python -m repro.obs OBS_DIR
 
-Reads only the JSONL artifacts an ``--obs-dir`` run wrote; ``--chrome``
-additionally exports the span stream as Chrome-trace/Perfetto JSON
-(open in ``chrome://tracing`` or https://ui.perfetto.dev).
+Reads only the JSONL artifacts an ``--obs-dir`` run wrote.  For a
+timeline view, run the program under ``jax.profiler.trace(dir,
+create_perfetto_trace=True)``: the program's spans sit there beside the
+device's lanes.
 """
 from __future__ import annotations
 
 import argparse
-import json
 import sys
 
 from repro.obs import report as R
@@ -18,8 +18,6 @@ from repro.obs import report as R
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(prog="python -m repro.obs")
     ap.add_argument("obs_dir", help="directory an --obs-dir run wrote")
-    ap.add_argument("--chrome", metavar="OUT.json", default=None,
-                    help="also export spans as a Chrome-trace JSON file")
     args = ap.parse_args(argv)
 
     run = R.load_run(args.obs_dir)
@@ -28,12 +26,6 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 1
     print(R.render(run))
-    if args.chrome:
-        doc = R.run_chrome_trace(run)
-        with open(args.chrome, "w") as f:
-            json.dump(doc, f)
-        print(f"\nchrome trace -> {args.chrome} "
-              f"({len(doc['traceEvents'])} events)")
     return 0
 
 

@@ -13,7 +13,6 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from repro.controlplane.events import Event, read_events
-from repro.obs.trace import chrome_trace
 
 STREAMS = ("spans", "steps", "decisions", "metrics")
 
@@ -64,23 +63,18 @@ def calibration_report(decisions) -> Dict[str, dict]:
 
 
 def timeline_summary(spans) -> List[dict]:
-    """Aggregate span records per (track, name): count, total/mean µs."""
-    agg: Dict[tuple, dict] = {}
+    """Aggregate span records per name: count, total/mean µs."""
+    agg: Dict[str, dict] = {}
     for s in _records(spans):
-        key = (s.get("track", "main"), s["name"])
-        a = agg.setdefault(key, {"track": key[0], "name": key[1],
-                                 "count": 0, "total_us": 0.0,
-                                 "depth": s.get("depth", 1)})
+        a = agg.setdefault(s["name"], {"name": s["name"], "count": 0,
+                                       "total_us": 0.0,
+                                       "depth": s.get("depth", 1)})
         a["count"] += 1
         a["total_us"] += float(s["dur_us"])
-    rows = sorted(agg.values(), key=lambda a: (a["track"], -a["total_us"]))
+    rows = sorted(agg.values(), key=lambda a: -a["total_us"])
     for a in rows:
         a["mean_us"] = a["total_us"] / a["count"]
     return rows
-
-
-def run_chrome_trace(run: Dict[str, List[Event]]) -> dict:
-    return chrome_trace(_records(run["spans"]))
 
 
 def _fmt(v, pat="{:.3f}") -> str:
@@ -103,11 +97,11 @@ def render(run: Dict[str, List[Event]]) -> str:
     rows = timeline_summary(run["spans"])
     if rows:
         lines.append("\n-- timeline (per span, by total time) --")
-        lines.append(f"{'track':<12} {'span':<28} {'count':>6} "
+        lines.append(f"{'span':<32} {'count':>6} "
                      f"{'total ms':>10} {'mean us':>10}")
         for a in rows:
             pad = "  " * (max(int(a["depth"]), 1) - 1)
-            lines.append(f"{a['track']:<12} {pad + a['name']:<28} "
+            lines.append(f"{pad + a['name']:<32} "
                          f"{a['count']:>6} {a['total_us'] / 1e3:>10.2f} "
                          f"{a['mean_us']:>10.1f}")
 

@@ -6,7 +6,8 @@ everything stays in memory (benches read ``obs.steps.records``
 directly); with a directory, four JSONL streams are written with the
 ``controlplane.events`` conventions:
 
-  ``spans.jsonl``      tracer spans           (kind ``span``)
+  ``spans.jsonl``      every span the process completes while the
+                       run is open (kind ``span``)
   ``steps.jsonl``      trainer step records   (kind ``step``)
   ``decisions.jsonl``  scored cutoff decisions (kind ``decision``)
   ``metrics.jsonl``    drained device collectors + run markers
@@ -112,6 +113,7 @@ class ObsRun:
         self.drain()
         self._meta_log.emit(self._meta_log.autotick(), "run", phase="end",
                             summary=self.metrics.summary())
+        self.trace.close()
         for log in (self._span_log, self._step_log, self._dec_log,
                     self._meta_log):
             log.close()

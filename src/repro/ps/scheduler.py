@@ -24,6 +24,8 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Callable, List, Optional, Sequence
 
+from repro.obs.trace import span
+
 
 @dataclass(frozen=True)
 class JobView:
@@ -64,6 +66,10 @@ class RoundRobinScheduler:
 
     def order(self, views: Sequence[JobView],
               capacity: Optional[int] = None) -> List[str]:
+        with span("ps.schedule"):
+            return self._order(views, capacity)
+
+    def _order(self, views, capacity):
         ring = sorted(views, key=lambda v: v.admit_order)
         cap = _capacity(ring, capacity)
         if cap == 0:
@@ -87,8 +93,9 @@ class PriorityScheduler:
 
     def order(self, views: Sequence[JobView],
               capacity: Optional[int] = None) -> List[str]:
-        ranked = sorted(views, key=lambda v: (-v.priority, v.job_id))
-        return [v.job_id for v in ranked[:_capacity(views, capacity)]]
+        with span("ps.schedule"):
+            ranked = sorted(views, key=lambda v: (-v.priority, v.job_id))
+            return [v.job_id for v in ranked[:_capacity(views, capacity)]]
 
 
 class ShortestStepScheduler:
@@ -115,6 +122,10 @@ class ShortestStepScheduler:
 
     def order(self, views: Sequence[JobView],
               capacity: Optional[int] = None) -> List[str]:
+        with span("ps.schedule"):
+            return self._order(views, capacity)
+
+    def _order(self, views, capacity):
         age = self._age
 
         def key(v: JobView):

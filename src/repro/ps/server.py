@@ -43,7 +43,6 @@ amortizes dispatch, it never changes the decision.
 from __future__ import annotations
 
 import functools
-from contextlib import nullcontext
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
@@ -54,6 +53,7 @@ import numpy as np
 from repro.core import controller as C
 from repro.core.cutoff import order_stats
 from repro.core.runtime_model.api import RuntimeModel, stack_models_padded
+from repro.obs.trace import span
 
 
 # ---------------------------------------------------------------------------
@@ -328,10 +328,9 @@ class PSServer:
         self.refit_async = refit_async
         self.fallback_warmup = fallback_warmup
         self.refit_retries = refit_retries
-        # optional repro.obs.ObsRun: flush/dispatch spans are host
-        # perf_counter edges only (they time DISPATCH, the async cost
-        # model) and refit-gate activity lands on host counters — with
-        # obs attached the decision sequence is bit-identical
+        # optional repro.obs.ObsRun: refit-gate activity lands on its
+        # host counters — with obs attached the decision sequence is
+        # bit-identical
         self.obs = obs
         self._buckets: Dict[tuple, _Bucket] = {}
         self._queue: List[dict] = []
@@ -500,7 +499,10 @@ class PSServer:
     # -- the decision path ----------------------------------------------
     # reprolint: hot-path
     def predict_cutoff(self, job_id: str) -> int:
-        job = self.registry[job_id]
+        with span("ps.predict_cutoff"):
+            return self._predict_cutoff(self.registry[job_id])
+
+    def _predict_cutoff(self, job: PSJob) -> int:
         if job.queued:
             self.flush()
         self._poll_refit(job)
@@ -537,9 +539,10 @@ class PSServer:
         sample clouds stay on device."""
         h = out.get("host")
         if h is None:
-            # reprolint: disable=host-sync-in-hot-path -- THE designated fetch: one device_get per batched dispatch, amortized over every job row it served
-            cut, mu, std, it = jax.device_get(
-                (out["cutoff"], out["mu"], out["std"], out["iter"]))
+            with span("ps.fetch"):
+                # reprolint: disable=host-sync-in-hot-path -- THE designated fetch: one device_get per batched dispatch, amortized over every job row it served
+                cut, mu, std, it = jax.device_get(
+                    (out["cutoff"], out["mu"], out["std"], out["iter"]))
             h = out["host"] = {"cutoff": np.asarray(cut),
                                "mu": np.asarray(mu),
                                "std": np.asarray(std),
@@ -567,19 +570,20 @@ class PSServer:
         from ``predict_cutoff`` (which already incremented), step+1 when
         prefetching."""
         b = self._buckets[jobs[0].bucket_sig]
-        keys = jnp.asarray(C._prng_key_rows(
-            [j.seed + d for j, d in zip(jobs, dsteps)]))
-        params, scales, widths, los = b.stacked()
-        slots = [j.slot for j in jobs]
-        if slots == list(range(len(b.jobs))):
-            cut, samp, mu, std, it = _full_decide(
-                params, b.rings, b.heads, keys, scales, widths, los,
-                k_samples=b.k_samples)
-        else:
-            idx = jnp.asarray(slots, jnp.int32)
-            cut, samp, mu, std, it = _subset_decide(
-                params, b.rings, b.heads, idx, keys, scales, widths, los,
-                k_samples=b.k_samples)
+        with span("ps.decide"):
+            keys = jnp.asarray(C._prng_key_rows(
+                [j.seed + d for j, d in zip(jobs, dsteps)]))
+            params, scales, widths, los = b.stacked()
+            slots = [j.slot for j in jobs]
+            if slots == list(range(len(b.jobs))):
+                cut, samp, mu, std, it = _full_decide(
+                    params, b.rings, b.heads, keys, scales, widths, los,
+                    k_samples=b.k_samples)
+            else:
+                idx = jnp.asarray(slots, jnp.int32)
+                cut, samp, mu, std, it = _subset_decide(
+                    params, b.rings, b.heads, idx, keys, scales, widths,
+                    los, k_samples=b.k_samples)
         self.dispatches += 1
         out = {"cutoff": cut, "samples": samp, "mu": mu, "std": std,
                "iter": it}
@@ -587,7 +591,11 @@ class PSServer:
             j.pending = (d, row, out)
 
     def observe(self, job_id: str, times, finished_mask=None):
-        job = self.registry[job_id]
+        with span("ps.observe"):
+            self._observe(self.registry[job_id], times, finished_mask)
+
+    def _observe(self, job: PSJob, times, finished_mask):
+        job_id = job.job_id
         t = np.asarray(times, np.float64)
         if t.shape != (job.width,):
             raise ValueError(
@@ -658,14 +666,9 @@ class PSServer:
         masks + traced censor flags).  Returns the dispatches issued."""
         if not self._queue:
             return 0
-        # spans stamp host perf_counter edges around the (async) dispatch
-        # calls; obs attrs are plain host ints already on the queue
-        # entries, so instrumentation adds zero device syncs here
-        tracer = self.obs.trace if self.obs is not None else None
-        fspan = (tracer.span("ps.flush", track="ps", tick=self.ticks,
-                             queued=len(self._queue))
-                 if tracer is not None else nullcontext())
-        with fspan:
+        # the spans time the host packing and the (async) dispatch: they
+        # add no device sync here
+        with span("ps.flush", tick=self.ticks, queued=len(self._queue)):
             queue, self._queue = self._queue, []
             groups: Dict[tuple, list] = {}
             for e in queue:
@@ -676,10 +679,7 @@ class PSServer:
                 m, npd = len(entries), b.n_pad
                 slots = [e["job"].slot for e in entries]
                 gather = slots != list(range(len(b.jobs)))
-                dspan = (tracer.span("ps.dispatch", track="ps", jobs=m,
-                                     n_pad=npd, gather=gather)
-                         if tracer is not None else nullcontext())
-                with dspan:
+                with span("ps.pack"):
                     # one packed upload:
                     # [times, mask, mu, std] + keys/steps/cen
                     pack = np.zeros((4, m, npd), np.float32)
@@ -700,6 +700,7 @@ class PSServer:
                         [e["job"].seed + e["dstep"] for e in entries])
                     keys[:, 2:] = C._prng_key_rows(
                         [e["job"].seed + 1_000_003 for e in entries])
+                with span("ps.dispatch", jobs=m, gather=gather):
                     params, scales, widths, los = b.stacked()
                     args = (jnp.asarray(pack), jnp.asarray(keys),
                             jnp.asarray(steps), jnp.asarray(cen),
@@ -715,12 +716,12 @@ class PSServer:
                             _subset_observe_decide(
                                 params, b.rings, b.heads, idx, *args,
                                 k_samples=b.k_samples))
-                    issued += 1
-                    out = {"cutoff": cut, "samples": samp, "mu": mu,
-                           "std": std, "iter": it}
-                    for row, e in enumerate(entries):
-                        e["job"].pending = (e["dstep"], row, out)
-                        e["job"].queued = False
+                issued += 1
+                out = {"cutoff": cut, "samples": samp, "mu": mu,
+                       "std": std, "iter": it}
+                for row, e in enumerate(entries):
+                    e["job"].pending = (e["dstep"], row, out)
+                    e["job"].queued = False
         self.dispatches += issued
         self.ticks += 1
         return issued
@@ -866,10 +867,7 @@ class PSServer:
                 lambda: self._fit_model(job, rows, n, seed),
                 job.resize_count)
         else:
-            span = (self.obs.trace.span("ps.refit", track="ps",
-                                        job=job.job_id, width=n)
-                    if self.obs is not None else nullcontext())
-            with span:
+            with span("ps.refit", job=job.job_id, width=n):
                 model = self._fit_model(job, rows, n, seed)
             self._install_refit(job, model)
 

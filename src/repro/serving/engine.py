@@ -6,7 +6,6 @@ production mesh path reuses the same decode_step the dry-run lowers
 """
 from __future__ import annotations
 
-from contextlib import nullcontext
 from dataclasses import dataclass
 from typing import Optional
 
@@ -15,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import model as M
+from repro.obs.trace import span
 
 
 @dataclass
@@ -22,10 +22,6 @@ class ServeEngine:
     cfg: object
     params: object
     max_len: int = 512
-    # optional repro.obs.ObsRun: prefill/decode/fetch spans stamp host
-    # perf_counter edges around the (async) dispatches — they time
-    # DISPATCH, never insert a block_until_ready
-    obs: object = None
 
     def __post_init__(self):
         cfg = self.cfg
@@ -54,19 +50,15 @@ class ServeEngine:
             batch["frames"] = (jnp.asarray(frames) if frames is not None
                                else jnp.zeros(
                 (B, self.cfg.encoder_seq_len, self.cfg.d_model)))
-        tracer = self.obs.trace if self.obs is not None else None
-
-        def _span(name, **attrs):
-            return (tracer.span(name, track="serving", **attrs)
-                    if tracer is not None else nullcontext())
-
-        with _span("serve.prefill", batch=B, seq=S):
+        # the spans time DISPATCH of the async calls; only serve.fetch
+        # waits for the device
+        with span("serve.prefill", batch=B, seq=S):
             last_logits, caches = self._prefill(self.params, batch)
         caches = M.pad_caches(caches, S + n_new)
         key = jax.random.PRNGKey(seed)
         out = []
         nxt = self._sample(last_logits, temperature, key)
-        with _span("serve.decode", batch=B, n_new=n_new):
+        with span("serve.decode", batch=B, n_new=n_new):
             for t in range(n_new):
                 # keep the loop transfer-free: collect DEVICE arrays so
                 # each decode dispatch overlaps the previous step instead
@@ -76,7 +68,7 @@ class ServeEngine:
                                               jnp.int32(S + t), caches)
                 key, sub = jax.random.split(key)
                 nxt = self._sample(logits[:, 0], temperature, sub)
-        with _span("serve.fetch", batch=B, n_new=n_new):
+        with span("serve.fetch", batch=B, n_new=n_new):
             # reprolint: disable=host-sync-in-hot-path -- the ONE designated fetch: all n_new tokens come back in a single transfer after the loop has been fully enqueued
             return np.asarray(jnp.stack(out, axis=1))
 

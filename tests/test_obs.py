@@ -4,22 +4,28 @@ The load-bearing promise of ``repro.obs`` is that attaching it changes
 NOTHING: a seeded Trainer run and a J=3 PSServer run must produce
 bit-identical losses and cutoff sequences with obs on vs off.  Around
 that sit the mechanism contracts — ring overflow drops oldest and is
-counted, spans nest lexically and export as Chrome trace, the JSONL
-streams keep the ``controlplane.events`` monotone-seq / torn-tail
-conventions, and the CLI renders a run from artifacts alone.
+counted, spans nest lexically and land in the profiler's trace on its
+clock (and in the profiled summary), the JSONL streams keep the
+``controlplane.events`` monotone-seq / torn-tail conventions, and the
+CLI renders a run from artifacts alone.
 """
-import json
+import gc
+import glob
+import os
+import time
 
 import numpy as np
 import pytest
+from jax.profiler import TraceAnnotation
 
 from repro.cluster.simulator import ClusterSim, paper_cluster_158
 from repro.core.controller import CutoffController
 from repro.core.cutoff import order_stats
 from repro.core.runtime_model.api import RuntimeModel
 from repro.obs import ObsRun
+from repro.obs import trace as obs_trace
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import OBS_KINDS, ObsLog, Tracer, chrome_trace
+from repro.obs.trace import OBS_KINDS, ObsLog, Tracer
 from repro.ps import PSServer
 
 
@@ -70,6 +76,14 @@ def test_ring_rejects_arity_and_column_drift():
 # ---------------------------------------------------------------------------
 
 
+def _parent(rec, spans):
+    """The record of the span that directly encloses ``rec``."""
+    end = rec["ts_us"] + rec["dur_us"]
+    return next(s for s in spans if s["depth"] == rec["depth"] - 1
+                and s["ts_us"] <= rec["ts_us"]
+                and end <= s["ts_us"] + s["dur_us"])
+
+
 def _scale_model(n, trace, seed=0):
     rm = RuntimeModel(n_workers=n, lag=10).init(seed)
     rm.norm_scale = float(2.0 * trace[:21].mean())
@@ -116,8 +130,8 @@ def test_trainer_bit_exact_with_obs_attached():
     """Seeded 50-step run: identical losses AND cutoff sequences with the
     full spine on (spans + ring pushes + quality wrapper) vs bare."""
     bare = _run_trainer(None)
-    obs = ObsRun()
-    inst = _run_trainer(obs)
+    with ObsRun() as obs:
+        inst = _run_trainer(obs)
     assert [h["c"] for h in inst.history] == [h["c"] for h in bare.history]
     assert ([h["loss"] for h in inst.history]
             == [h["loss"] for h in bare.history])
@@ -126,8 +140,18 @@ def test_trainer_bit_exact_with_obs_attached():
     assert len(obs.steps) == len(bare.history) == 50
     assert len(obs.decisions.records) == 50
     names = {s["name"] for s in obs.trace.spans}
-    assert {"trainer.step", "controller.predict_cutoff", "train.dispatch",
-            "controller.observe", "obs.drain"} <= names
+    assert {"trainer.step", "trainer.timer", "trainer.batch",
+            "train.dispatch", "controller.predict_cutoff",
+            "controller.fetch", "controller.observe", "obs.drain"} <= names
+    spans = obs.trace.spans
+    for child, parent in (("trainer.batch", "trainer.step"),
+                          ("trainer.timer", "trainer.step"),
+                          ("train.dispatch", "trainer.step"),
+                          ("controller.predict_cutoff", "trainer.step"),
+                          ("controller.observe", "trainer.step"),
+                          ("controller.fetch", "controller.predict_cutoff")):
+        assert {_parent(s, spans)["name"] for s in spans
+                if s["name"] == child} == {parent}, child
     assert obs.metrics.ring("trainer[dmm]",
                             ("loss", "gnorm", "c", "iter_time")).pushed == 50
 
@@ -155,26 +179,39 @@ def _drive_ps(obs, J=3, steps=25, n=8):
         srv.flush()
     if obs is not None:
         obs.drain()
-    return seqs
+    return seqs, [srv.window_array(f"job{j}") for j in range(J)]
 
 
 def test_psserver_bit_exact_with_obs_attached():
-    """J=3 batched server: identical cutoff sequences with flush spans +
-    refit counters + per-job quality wrappers on vs off."""
-    bare = _drive_ps(None)
-    obs = ObsRun()
-    inst = _drive_ps(obs)
+    """J=3 batched server: identical cutoff sequences and windows with
+    spans + refit counters + per-job quality wrappers on vs off."""
+    bare, bare_windows = _drive_ps(None)
+    with ObsRun() as obs:
+        inst, inst_windows = _drive_ps(obs)
     assert inst == bare
-    assert len(set(map(tuple, bare))) == 3     # three distinct jobs
-    # flush spans recorded, dispatch nested strictly inside flush
+    for a, b in zip(inst_windows, bare_windows):
+        np.testing.assert_array_equal(a, b)
+    # three distinct jobs: each job's window is its own
+    for i in range(3):
+        for k in range(i + 1, 3):
+            assert not np.array_equal(bare_windows[i], bare_windows[k])
+    # the PS span tree: the decision fetch inside predict_cutoff, the
+    # host packing and the dispatch inside flush
+    spans = obs.trace.spans
     by_name = {}
-    for s in obs.trace.spans:
+    for s in spans:
         by_name.setdefault(s["name"], []).append(s)
     assert len(by_name["ps.flush"]) == 25
-    assert by_name["ps.dispatch"]
-    flush_depth = by_name["ps.flush"][0]["depth"]
-    assert all(s["depth"] == flush_depth + 1
-               for s in by_name["ps.dispatch"])
+    assert len(by_name["ps.predict_cutoff"]) == 3 * 25
+    assert len(by_name["ps.observe"]) == 3 * 25
+    assert by_name["ps.fetch"]
+    for child, parent in (("ps.fetch", "ps.predict_cutoff"),
+                          ("ps.pack", "ps.flush"),
+                          ("ps.dispatch", "ps.flush")):
+        assert {_parent(s, spans)["name"] for s in by_name[child]} == {
+            parent}, child
+    # one packing and one dispatch per flush: one bucket
+    assert len(by_name["ps.pack"]) == len(by_name["ps.dispatch"]) == 25
     # every decision scored with the shared schema, lazy samples included
     recs = obs.decisions.records
     assert len(recs) == 3 * 25
@@ -183,15 +220,169 @@ def test_psserver_bit_exact_with_obs_attached():
 
 
 # ---------------------------------------------------------------------------
-# Spans + chrome export.
+# Spans: the profiler's trace, the profiled summary, the no-op path.
 # ---------------------------------------------------------------------------
 
 
-def test_span_nesting_and_chrome_export():
+def _profile(fn, log_dir):
+    """Run ``fn`` under the CPU profiler; returns the host events
+    of the written ``.xplane.pb`` as (plane, name, start_ns, end_ns)."""
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(log_dir), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(log_dir), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    pd = ProfileData.from_file(path)
+    return [(plane.name, ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+            for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+
+
+def _nested_spans():
+    for i in range(3):
+        with obs_trace.span("t.outer", tick=i):
+            time.sleep(0.004)
+            for _ in range(2):
+                with obs_trace.span("t.inner"):
+                    time.sleep(0.002)
+    with TraceAnnotation("t.raw"):
+        time.sleep(0.001)
+
+
+@pytest.fixture(scope="module")
+def profiled_run(tmp_path_factory):
+    """The nested spans run once under the profiler: (the profiled
+    summary, the xplane's host events)."""
+    obs_trace.reset_profiled()
+    try:
+        events = _profile(_nested_spans, tmp_path_factory.mktemp("prof"))
+        return obs_trace.profiled(), events
+    finally:
+        obs_trace.reset_profiled()
+
+
+def test_cpu_profiler_records_host_plane(profiled_run):
+    _, events = profiled_run
+    raw = [e for e in events if e[1] == "t.raw"]
+    assert len(raw) == 1 and raw[0][0] == "/host:CPU"
+
+
+def test_program_spans_land_in_xplane_nested(profiled_run):
+    """Bare names (the ``tick=`` attr is not in the event name), each
+    inner span inside an outer one, on the profiler's clock."""
+    _, events = profiled_run
+    outer = [e for e in events if e[1] == "t.outer"]
+    inner = [e for e in events if e[1] == "t.inner"]
+    assert len(outer) == 3 and len(inner) == 6
+    assert not [e for e in events if e[1].startswith("t.outer")
+                and e[1] != "t.outer"]
+    for _, _, s, e in inner:
+        assert any(os_ <= s and e <= oe for _, _, os_, oe in outer)
+
+
+def test_profiled_summary_matches_xplane(profiled_run):
+    summary, events = profiled_run
+    spans = summary["spans"]
+    assert set(spans) == {"t.outer", "t.inner"}    # t.raw is not ours
+    for name in spans:
+        mine = [e for e in events if e[1] == name]
+        assert spans[name]["count"] == len(mine)
+        xplane_s = sum(e - s for _, _, s, e in mine) / 1e9
+        assert spans[name]["total_s"] == pytest.approx(xplane_s, rel=0.05)
+
+
+def test_profiled_self_time_is_total_minus_children(profiled_run):
+    summary, _ = profiled_run
+    outer, inner = summary["spans"]["t.outer"], summary["spans"]["t.inner"]
+    assert outer["self_s"] == pytest.approx(
+        outer["total_s"] - inner["total_s"], rel=1e-9)
+    assert inner["self_s"] == pytest.approx(inner["total_s"], rel=1e-9)
+    # only the outer spans are top-level
+    assert summary["top_level_s"] == pytest.approx(outer["total_s"],
+                                                   rel=1e-9)
+    assert outer["self_s"] >= 3 * 0.004
+
+
+def test_profiled_summary_counts_every_thread(tmp_path):
+    """Spans completing on several threads at once, under a profiler
+    trace: no update of the summary is lost, and each thread nests
+    its own spans."""
+    import sys
+    import threading
+
+    n_threads, n_spans = 8, 300
+
+    def work():
+        for _ in range(n_spans):
+            with obs_trace.span("t.thread"):
+                with obs_trace.span("t.child"):
+                    pass
+
+    def many():
+        threads = [threading.Thread(target=work) for _ in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+
+    obs_trace.reset_profiled()
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        _profile(many, tmp_path)
+        spans = obs_trace.profiled()["spans"]
+    finally:
+        sys.setswitchinterval(interval)
+        obs_trace.reset_profiled()
+    assert spans["t.thread"]["count"] == spans["t.child"]["count"] == (
+        n_threads * n_spans)
+    assert spans["t.thread"]["self_s"] == pytest.approx(
+        spans["t.thread"]["total_s"] - spans["t.child"]["total_s"])
+
+
+def test_span_is_a_shared_noop_without_listeners():
+    gc.collect()
+    obs_trace.reset_profiled()
+    assert not TraceAnnotation.is_enabled()
+    s = obs_trace.span("t.idle", step=1)
+    assert s is obs_trace.NO_SPAN and obs_trace.span("t.other") is s
+    with s:
+        pass
+    assert obs_trace.profiled() == {}
+    # an open tracer listens; once closed, nobody does again
     tracer = Tracer()
-    with tracer.span("outer", track="t", tick=3):
-        with tracer.span("inner", track="t", step=9):
-            pass
+    with obs_trace.span("t.idle"):
+        pass
+    tracer.close()
+    assert [r["name"] for r in tracer.spans] == ["t.idle"]
+    assert obs_trace.span("t.idle") is obs_trace.NO_SPAN
+    assert obs_trace.profiled() == {}        # the profiler never traced
+
+
+def test_span_nesting_and_chrome_export(tmp_path):
+    """One span call, both listeners: an open Tracer's records (depth,
+    attrs nested under ``attrs``) and the profiler trace, which replaces
+    the Chrome-trace JSON export (a Perfetto view of the same spans)."""
+    tracer = Tracer()
+
+    def nested():
+        with obs_trace.span("outer", tick=3):
+            with obs_trace.span("inner", step=9):
+                pass
+
+    try:
+        events = _profile(nested, tmp_path)
+    finally:
+        tracer.close()
+        obs_trace.reset_profiled()
     inner, outer = tracer.spans            # completion order: inner first
     assert (outer["name"], outer["depth"]) == ("outer", 1)
     assert (inner["name"], inner["depth"]) == ("inner", 2)
@@ -200,13 +391,13 @@ def test_span_nesting_and_chrome_export():
     assert outer["attrs"] == {"tick": 3} and inner["attrs"] == {"step": 9}
     assert outer["ts_us"] <= inner["ts_us"]
     assert outer["dur_us"] >= inner["dur_us"]
+    assert "track" not in outer
 
-    doc = chrome_trace(tracer.spans)
-    evs = [e for e in doc["traceEvents"] if e["ph"] == "X"]
-    meta = [e for e in doc["traceEvents"] if e["ph"] == "M"]
-    assert [e["name"] for e in evs] == ["outer", "inner"]  # start order
-    assert evs[0]["args"] == {"tick": 3, "depth": 1}
-    assert meta[0]["args"]["name"] == "t"
+    ours = sorted((e for e in events if e[1] in ("outer", "inner")),
+                  key=lambda e: e[2])
+    assert [e[1] for e in ours] == ["outer", "inner"]    # start order
+    (_, _, o0, o1), (_, _, i0, i1) = ours
+    assert o0 <= i0 and i1 <= o1
 
 
 # ---------------------------------------------------------------------------
@@ -258,15 +449,14 @@ def test_cli_renders_timeline_and_calibration(recorded_run, tmp_path,
                                               capsys):
     from repro.obs.__main__ import main
 
-    chrome = tmp_path / "trace.json"
-    assert main([recorded_run, "--chrome", str(chrome)]) == 0
+    assert main([recorded_run]) == 0
     out = capsys.readouterr().out
     assert "12 step records" in out
     assert "timeline" in out and "decision quality" in out
     assert "trainer.step" in out and "dmm" in out
-    with open(chrome) as f:
-        doc = json.load(f)
-    assert any(e.get("ph") == "X" for e in doc["traceEvents"])
+    # the JSON export is gone: the profiler trace is the timeline view
+    with pytest.raises(SystemExit):
+        main([recorded_run, "--chrome", str(tmp_path / "trace.json")])
 
 
 def test_cli_empty_dir_is_an_error(tmp_path):
