@@ -1,0 +1,81 @@
+"""Fused causal self-attention, forward and backward: the bundled splash
+attention Pallas kernels (``jax.experimental.pallas.ops.tpu``).
+
+TARGET: TPU MXU/VMEM.  One MQA kernel per (batch, KV head), vmapped, so
+the G query heads of a group share one K/V stream and K/V is never
+repeated.  Operands go to the MXU in their own dtype (bf16 in training);
+logits, the online softmax's running max and sum, and the accumulators
+are float32.  The forward keeps only the output and the per-row
+logsumexp as residuals; the backward's dq and dk/dv kernels recompute
+the probabilities from them.  Blocks above the diagonal are skipped,
+their compute and their DMA both, so no (S, S) score matrix is ever
+written to HBM.
+
+The mask is by index: query row i sees keys 0..i.  That is the contract
+``models.attention.attn_core`` states for row-uniform positions, which
+every pipeline here produces (``arange(S)`` per row).
+
+VALIDATED in interpret mode on CPU against ``ref.reference_attention``
+and ``attn_core``, forward and gradients — see tests/test_kernels.py and
+tests/test_fused_attention.py.
+"""
+from __future__ import annotations
+
+import functools
+import math
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental.pallas.ops.tpu.splash_attention import (
+    splash_attention_kernel as splash,
+    splash_attention_mask as splash_mask,
+)
+
+# q and kv blocks, largest first (the kernels need multiples of 128), and
+# the kv span the forward and dk/dv kernels compute at a time.  Chosen on
+# a TPU v5e at qwen2-0.5b's train4k shapes (PERF.md section 6).
+BLOCKS = (1024, 512, 256, 128)
+COMPUTE = 512
+
+
+def block_size(seq_len: int) -> Optional[int]:
+    """The q and kv block of every kernel at ``seq_len``: the largest of
+    ``BLOCKS`` that divides it, or None where none does."""
+    return next((b for b in BLOCKS if seq_len % b == 0), None)
+
+
+@functools.lru_cache(maxsize=None)
+def _kernel(seq_len: int, group: int, interpret: bool):
+    b = block_size(seq_len)
+    mask = splash_mask.MultiHeadMask(
+        [splash_mask.CausalMask((seq_len, seq_len))] * group)
+    c = min(b, COMPUTE)
+    sizes = splash.BlockSizes(
+        block_q=b, block_kv=b, block_kv_compute=c,
+        block_q_dkv=b, block_kv_dkv=b, block_kv_dkv_compute=c,
+        block_q_dq=b, block_kv_dq=b)
+    # the mask's block tables are kept as numpy: arrays made under the
+    # trace (and mesh) that first asks could not be held by later ones
+    with jax.ensure_compile_time_eval():
+        kernel = splash.make_splash_mqa_single_device(
+            mask, block_sizes=sizes, interpret=interpret)
+    return jax.tree.map(np.asarray, kernel)
+
+
+def causal_attention(q, k, v, *, interpret: bool = False):
+    """q: (B, S, H, hd); k/v: (B, S, KV, hd) -> (B, S, H, hd).
+
+    Query head h reads KV head h // (H // KV).  S must tile
+    (``block_size(S)`` not None)."""
+    B, S, H, hd = q.shape
+    KV = k.shape[2]
+    G = H // KV
+    kernel = _kernel(S, G, interpret)
+    q = q * jnp.asarray(1.0 / math.sqrt(hd), q.dtype)
+    qh = q.reshape(B, S, KV, G, hd).transpose(0, 2, 3, 1, 4)
+    kh = k.transpose(0, 2, 1, 3)
+    vh = v.transpose(0, 2, 1, 3)
+    o = jax.vmap(jax.vmap(kernel))(qh, kh, vh)        # (B, KV, G, S, hd)
+    return o.transpose(0, 3, 1, 2, 4).reshape(B, S, H, hd)

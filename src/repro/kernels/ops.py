@@ -14,7 +14,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import flash_attention as _fa
+from repro.kernels import causal_attention as _ca
 from repro.kernels import fused_adam as _ad
 from repro.kernels import masked_grad_agg as _ma
 from repro.kernels import mlstm_chunk as _ml
@@ -29,12 +29,19 @@ def _mode():
     return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
-def attention(q, k, v, *, causal=True, window=0):
+def attention_fuses(seq_len: int) -> bool:
+    """Whether ``attention`` at ``seq_len`` runs the fused kernel: a Pallas
+    backend, and a sequence the kernel's blocks tile."""
+    return _mode() != "xla" and _ca.block_size(seq_len) is not None
+
+
+def attention(q, k, v):
+    """Causal self-attention masked by index (``causal_attention``'s
+    contract); the dense reference under "xla"."""
     m = _mode()
     if m == "xla":
-        return ref.reference_attention(q, k, v, causal=causal, window=window)
-    return _fa.flash_attention(q, k, v, causal=causal, window=window,
-                               interpret=(m == "interpret"))
+        return ref.reference_attention(q, k, v, causal=True)
+    return _ca.causal_attention(q, k, v, interpret=(m == "interpret"))
 
 
 def mlstm(q, k, v, g, i, *, chunk=128):

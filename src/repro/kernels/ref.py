@@ -8,7 +8,7 @@ import jax.numpy as jnp
 
 
 def reference_attention(q, k, v, *, causal=True, window=0):
-    """Dense attention, the contract of kernels.flash_attention.
+    """Dense attention; causal, the contract of kernels.causal_attention.
 
     q: (B, Sq, H, hd); k/v: (B, Sk, KV, hd).  Query row i sits at global
     position i + Sk - Sq (aligned suffixes).

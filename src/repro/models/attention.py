@@ -7,9 +7,13 @@
 * ``attn_decode``   — single-token decode with the KV cache sequence-sharded
   over "model" and a flash-decoding (max/sum-exp psum) combine.
 
-Both wrap the same pure-jnp local core ``attn_core`` which is also the
-oracle contract implemented by the Pallas flash-attention kernel
-(`repro.kernels.flash_attention`).
+Both wrap the same pure-jnp local core ``attn_core``.  Causal
+self-attention over the whole sequence (``_fuses``) runs instead the
+fused flash attention, forward and backward (``kernels.ops.attention``,
+`repro.kernels.causal_attention`), which computes what ``attn_core``
+would without writing the (Sq, Sk) scores to HBM.  Each call counts
+itself ``attn.fused`` or ``attn.unfused`` (``repro.obs.trace.count``)
+when it is traced.
 """
 from __future__ import annotations
 
@@ -20,7 +24,9 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.dist import sharding as shd
+from repro.kernels import ops
 from repro.models import layers as L
+from repro.obs import trace
 from repro.perf.knobs import knobs
 
 NEG_INF = -1e30
@@ -157,16 +163,47 @@ def attn_core(q, k, v, qpos, kpos, *, causal=True, window=0, softcap=0.0,
 # ---------------------------------------------------------------------------
 
 
+def _fuses(Sq, Sk, qpos, kpos, *, causal, window, softcap) -> bool:
+    """Whether the fused kernel computes this call: causal self-attention
+    (Sq == Sk, keys at ``arange``) whose window masks nothing, no softcap,
+    2-D positions, a sequence the kernel tiles, a Pallas backend.  The
+    kernel masks by index, which is ``attn_core``'s contract for the
+    row-uniform ``arange(S)`` positions every pipeline here produces."""
+    return (causal and not softcap and (window == 0 or window >= Sk)
+            and Sq == Sk and kpos is None and qpos.ndim == 2
+            and ops.attention_fuses(Sk))
+
+
+def _fused(q, k, v, lay):
+    """``ops.attention`` on every batch shard of the mesh (a Pallas kernel
+    is not partitioned by GSPMD)."""
+    if lay.mesh is None:
+        return ops.attention(q, k, v)
+    dp = P(lay.dp_for(q.shape[0]))
+    return jax.shard_map(ops.attention, mesh=lay.mesh, in_specs=(dp,) * 3,
+                         out_specs=dp, check_vma=False)(q, k, v)
+
+
 def attention_sp(q, k, v, qpos, *, causal=True, window=0, softcap=0.0,
                  q_chunk=None, kpos=None):
     """q sequence-sharded over "model"; k/v gathered to full sequence.
 
-    kpos defaults to arange over the full (gathered) key length — correct for
+    qpos: (B, Sq), or mrope's (3, B, Sq), whose first row masks.  kpos
+    defaults to arange over the full (gathered) key length — correct for
     self-attention where keys span the whole global sequence.
     """
     lay = shd.layout()
     Sk = k.shape[1]
-    if lay.mesh is None or lay.mode != "train_sp" or lay.model_axis is None:
+    sp = (lay.mesh is not None and lay.mode == "train_sp"
+          and lay.model_axis is not None)
+    fused = _fuses(q.shape[1] // (lay.n_shards if sp else 1), Sk, qpos,
+                   kpos, causal=causal, window=window, softcap=softcap)
+    trace.count("attn.fused" if fused else "attn.unfused")
+    if qpos.ndim == 3:
+        qpos = qpos[0]
+    if not sp:
+        if fused:
+            return _fused(q, k, v, lay)
         kp = kpos if kpos is not None else jnp.arange(Sk)
         return attn_core(q, k, v, qpos, kp, causal=causal, window=window,
                          softcap=softcap, q_chunk=q_chunk)
@@ -208,14 +245,17 @@ def attention_sp(q, k, v, qpos, *, causal=True, window=0, softcap=0.0,
         )(q, k, v, qpos)
 
     def body(q_l, k_f, v_f, qpos_l):
+        if fused:
+            return ops.attention(q_l, k_f, v_f)
         kp = jnp.arange(k_f.shape[1])
         return attn_core(q_l, k_f, v_f, qpos_l, kp, causal=causal,
                          window=window, softcap=softcap, q_chunk=q_chunk)
 
+    # a Pallas kernel's outputs carry no varying mesh axes to check
     return jax.shard_map(
         body, mesh=lay.mesh,
         in_specs=(P(dp, m), P(dp), P(dp), P(dp, m)),
-        out_specs=P(dp, m),
+        out_specs=P(dp, m), check_vma=not fused,
     )(q, k, v, qpos)
 
 
@@ -261,6 +301,7 @@ def attn_decode(q, k_new, v_new, cache_k, cache_v, pos, *, window=0,
     """
     lay = shd.layout()
     B, _, H, hd = q.shape
+    trace.count("attn.unfused")
 
     if lay.mesh is None or lay.mode != "decode_tp" or lay.model_axis is None:
         ck = jax.lax.dynamic_update_slice_in_dim(cache_k, k_new, pos, axis=1)

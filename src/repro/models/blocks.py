@@ -74,9 +74,8 @@ def _attn_sublayer(cfg, p, x, ctx, cache, *, window: int, causal: bool = True,
         cache = dict(cache, k=ck, v=cv)
     else:
         q, k, v = A.project_qkv(cfg, p, x, ctx.positions, rope=rope)
-        qpos = ctx.positions[0] if ctx.positions.ndim == 3 else ctx.positions
-        y = A.attention_sp(q, k, v, qpos, causal=causal, window=window,
-                           softcap=cfg.attn_logit_softcap)
+        y = A.attention_sp(q, k, v, ctx.positions, causal=causal,
+                           window=window, softcap=cfg.attn_logit_softcap)
         if ctx.mode == "prefill":
             cache = {"k": k, "v": v}
     y = y.reshape(B, Sx, cfg.qkv_dim)

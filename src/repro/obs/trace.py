@@ -19,6 +19,13 @@ With neither listening, :func:`span` returns the shared :data:`NO_SPAN`
 — one list check and one ``TraceAnnotation.is_enabled()`` call, no
 clock read, no allocation.
 
+:func:`count` adds to a named counter kept from process start (or
+:func:`reset_counted`), listened to or not; :func:`counted` reads them.
+Code that counts while a step is traced (``attn.fused`` /
+``attn.unfused``: the attention sites ``models.attention`` lowers to
+the fused kernel or leaves unfused) counts once per trace, in a
+benchmark's set-up, so ``reset_profiled`` leaves the counters alone.
+
 Spans are host-edge timestamps only: ``time.perf_counter()`` at enter
 and exit, nothing else — a span around a jit dispatch measures dispatch
 (the async-dispatch cost model the repo optimizes for), never inserts a
@@ -58,6 +65,7 @@ _local = threading.local()    # .stack: this thread's open spans
 _summary: dict = {}           # name -> [count, total_s, self_s]
 _top = [0.0]                  # seconds in top-level profiled spans
 _summary_lock = threading.Lock()
+_counts: dict = {}            # name -> count (count / counted)
 
 
 class _NoSpan:
@@ -148,6 +156,22 @@ def reset_profiled():
     with _summary_lock:
         _summary.clear()
         _top[0] = 0.0
+
+
+def count(name: str):
+    with _summary_lock:
+        _counts[name] = _counts.get(name, 0) + 1
+
+
+def counted() -> dict:
+    """``{name: count}`` of every counter since process start."""
+    with _summary_lock:
+        return dict(_counts)
+
+
+def reset_counted():
+    with _summary_lock:
+        _counts.clear()
 
 
 # below the span machinery: importing the control plane imports its

@@ -7,7 +7,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ref
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.causal_attention import causal_attention
 from repro.kernels.fused_adam import fused_adam
 from repro.kernels.masked_grad_agg import masked_grad_agg
 from repro.kernels.mlstm_chunk import mlstm_chunk
@@ -21,17 +21,16 @@ SETTINGS = dict(max_examples=8, deadline=None)
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("causal,window", [(True, 0), (True, 64),
-                                           (False, 0)])
-def test_flash_attention_basic(causal, window):
+@pytest.mark.parametrize("heads", [(4, 4), (4, 2), (8, 1)])
+def test_flash_attention_basic(heads):
+    H, KV = heads
     key = jax.random.PRNGKey(0)
     ks = jax.random.split(key, 3)
-    q = jax.random.normal(ks[0], (2, 256, 4, 64))
-    k = jax.random.normal(ks[1], (2, 256, 2, 64))
-    v = jax.random.normal(ks[2], (2, 256, 2, 64))
-    out = flash_attention(q, k, v, causal=causal, window=window,
-                          interpret=True)
-    want = ref.reference_attention(q, k, v, causal=causal, window=window)
+    q = jax.random.normal(ks[0], (2, 256, H, 64))
+    k = jax.random.normal(ks[1], (2, 256, KV, 64))
+    v = jax.random.normal(ks[2], (2, 256, KV, 64))
+    out = causal_attention(q, k, v, interpret=True)
+    want = ref.reference_attention(q, k, v, causal=True)
     np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-5)
 
 
@@ -42,33 +41,35 @@ def test_flash_attention_basic(causal, window):
     heads=st.sampled_from([(4, 4), (4, 2), (8, 1)]),
     hd=st.sampled_from([32, 64, 128]),
     dtype=st.sampled_from([jnp.float32, jnp.bfloat16]),
-    causal=st.booleans(),
 )
-def test_flash_attention_sweep(b, s, heads, hd, dtype, causal):
+def test_flash_attention_sweep(b, s, heads, hd, dtype):
     H, KV = heads
-    key = jax.random.PRNGKey(hash((b, s, H, KV, hd, causal)) % 2**31)
+    key = jax.random.PRNGKey(hash((b, s, H, KV, hd)) % 2**31)
     ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], (b, s, H, hd)).astype(dtype)
     k = jax.random.normal(ks[1], (b, s, KV, hd)).astype(dtype)
     v = jax.random.normal(ks[2], (b, s, KV, hd)).astype(dtype)
-    out = flash_attention(q, k, v, causal=causal, interpret=True)
-    want = ref.reference_attention(q, k, v, causal=causal)
+    out = causal_attention(q, k, v, interpret=True)
+    want = ref.reference_attention(q, k, v, causal=True)
     atol = 2e-5 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(out.astype(np.float32),
                                want.astype(np.float32), atol=atol, rtol=0.05)
 
 
-def test_flash_matches_model_attention_core():
-    """The kernel contract equals the model stack's attn_core path."""
+@pytest.mark.parametrize("S", [1536, 2048])
+def test_flash_matches_model_attention_core(S):
+    """The kernel contract equals the model stack's attn_core path over
+    several blocks, those above the diagonal skipped: three of 512 at
+    1536, two of 1024 computed 512 keys at a time at 2048."""
     from repro.models.attention import attn_core
     key = jax.random.PRNGKey(3)
     ks = jax.random.split(key, 3)
-    q = jax.random.normal(ks[0], (2, 256, 4, 64))
-    k = jax.random.normal(ks[1], (2, 256, 2, 64))
-    v = jax.random.normal(ks[2], (2, 256, 2, 64))
-    qpos = jnp.broadcast_to(jnp.arange(256)[None], (2, 256))
-    core = attn_core(q, k, v, qpos, jnp.arange(256), causal=True, window=0)
-    kern = flash_attention(q, k, v, causal=True, interpret=True)
+    q = jax.random.normal(ks[0], (1, S, 4, 64))
+    k = jax.random.normal(ks[1], (1, S, 2, 64))
+    v = jax.random.normal(ks[2], (1, S, 2, 64))
+    qpos = jnp.arange(S)[None]
+    core = attn_core(q, k, v, qpos, jnp.arange(S), causal=True, window=0)
+    kern = causal_attention(q, k, v, interpret=True)
     np.testing.assert_allclose(core, kern, atol=3e-5, rtol=2e-5)
 
 

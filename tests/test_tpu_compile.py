@@ -16,6 +16,7 @@ from repro.configs.base import get_config
 from repro.core import controller as C
 from repro.core.cutoff import order_stats
 from repro.core.runtime_model.api import RuntimeModel
+from repro.kernels.causal_attention import causal_attention
 from repro.kernels.fused_adam import fused_adam
 from repro.kernels.masked_grad_agg import masked_grad_agg
 
@@ -70,6 +71,22 @@ def test_fused_adam_compiles_on_embedding_leaf(one_chip, dtype):
     hlo = fused_adam.lower(p, p, f32, f32, _spec((3,), jnp.float32, one_chip),
                            wd=0.01).compile().as_text()
     assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("heads", [(14, 2, 64), (24, 2, 128)])
+def test_causal_attention_compiles_forward_and_backward(one_chip, heads):
+    # qwen2-0.5b's and starcoder2-3b's attention at seq 4096 x batch 8
+    H, KV, hd = heads
+    B, S = 8, 4096
+    q = _spec((B, S, H, hd), jnp.bfloat16, one_chip)
+    kv = _spec((B, S, KV, hd), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(causal_attention(q, k, v).astype(jnp.float32))
+
+    hlo = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv).compile(
+    ).as_text()
+    assert hlo.count("tpu_custom_call") == 3     # forward, dq, dk/dv
 
 
 def test_fused_observe_decide_compiles_at_158(one_chip):
