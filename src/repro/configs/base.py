@@ -129,7 +129,10 @@ class ArchConfig:
         attn = d * self.qkv_dim + 2 * d * self.kv_dim + self.qkv_dim * d
         if self.attn_bias:
             attn += self.qkv_dim + 2 * self.kv_dim
-        per_layer = attn + 2 * d  # norms
+        if self.attn_out_bias:
+            attn += d
+        norm = 2 * d if self.norm == "layernorm" else d  # scale [+ bias]
+        per_layer = attn + 2 * norm
         total = 0
         for i in range(self.n_layers):
             ff = per_layer
@@ -139,11 +142,13 @@ class ArchConfig:
                 ff += n_e * 3 * d * e_ff + d * self.n_experts
             else:
                 dff = self.dense_d_ff if (self.family == "moe" and self.dense_d_ff) else self.d_ff
-                mult = 3 if self.mlp in ("swiglu", "geglu") else 2
-                ff += mult * d * dff
+                gated = self.mlp in ("swiglu", "geglu")
+                ff += (3 if gated else 2) * d * dff
+                if self.mlp_bias:
+                    ff += d if gated else dff + d
             total += ff
         total += self.vocab_size * d * (1 if self.tie_embeddings else 2)
-        total += d  # final norm
+        total += norm  # final norm
         return total
 
     def n_active_params(self) -> int:

@@ -1,6 +1,11 @@
 """StarCoder2-3B [arXiv:2402.19173; hf:bigcode/starcoder2-3b].
 
-GQA kv=2, LayerNorm, GELU MLP with bias, RoPE.
+GQA kv=2, LayerNorm, GELU MLP with bias, RoPE, tied head, as in the
+published config.json (3,030,371,328 parameters).
+
+The published 4,096-token sliding window is left out (``sliding_window``
+0): at sequences of 4,096 or fewer it masks nothing, so full causal
+attention is the same computation.  Longer sequences need the window.
 """
 from repro.configs.base import ArchConfig, register
 
@@ -9,6 +14,7 @@ CONFIG = register(ArchConfig(
     n_layers=30, d_model=3072, n_heads=24, n_kv_heads=2, head_dim=128,
     d_ff=12288, vocab_size=49152,
     norm="layernorm", norm_eps=1e-5, mlp="gelu", mlp_bias=True,
-    attn_bias=True, attn_out_bias=True, rope_theta=999_999.44,
+    attn_bias=True, attn_out_bias=True, rope_theta=999999.4420358813,
+    tie_embeddings=True,
     source="arXiv:2402.19173; hf",
 ))

@@ -15,7 +15,7 @@ from repro.perf.knobs import use_knobs
 
 mesh = make_mesh((2, 4), ("data", "model"))
 
-for name in ["qwen2-0.5b", "starcoder2-3b"]:  # tied + untied
+for name in ["qwen2-0.5b", "stablelm-3b"]:  # tied + untied
     cfg = get_config(name).reduced()
     key = jax.random.PRNGKey(0)
     params = M.init_model(cfg, key)

@@ -1,7 +1,13 @@
 """The 10 assigned architecture configs carry the exact assigned numbers."""
+import json
+import math
+import os
+
+import jax
 import pytest
 
 from repro.configs.base import SHAPES, all_archs, cells, get_config
+from repro.models import model as M
 
 ASSIGNED = {
     # name: (layers, d_model, heads, kv, d_ff, vocab)
@@ -68,3 +74,34 @@ def test_shapes_assigned():
     assert SHAPES["prefill_32k"].global_batch == 32
     assert SHAPES["decode_32k"].global_batch == 128
     assert SHAPES["long_500k"].seq_len == 524288
+
+
+# published parameter counts of the full models (config.json, tied heads)
+PUBLISHED_PARAMS = {"starcoder2-3b": 3_030_371_328,
+                    "qwen2-0.5b": 494_032_768}
+
+
+def _tree_params(cfg) -> int:
+    shapes = jax.eval_shape(lambda: M.init_model(cfg, jax.random.PRNGKey(0)))
+    return sum(math.prod(a.shape) for a in jax.tree.leaves(shapes))
+
+
+@pytest.mark.parametrize("name", sorted(PUBLISHED_PARAMS))
+def test_param_tree_is_published_count(name):
+    """The registry's parameter tree holds the published model, tied head
+    included, and the analytic count agrees with it to the parameter."""
+    cfg = get_config(name)
+    assert _tree_params(cfg) == PUBLISHED_PARAMS[name]
+    assert cfg.n_params() == PUBLISHED_PARAMS[name]
+
+
+def test_starcoder2_matches_benchmark_config():
+    """The benchmark's starcoder2-3b file and the registry agree on the
+    published tied head and RoPE base."""
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                        "chip", "configs", "starcoder2-3b.json")
+    with open(path) as f:
+        c = json.load(f)
+    cfg = get_config("starcoder2-3b")
+    assert cfg.tie_embeddings is c["tie_word_embeddings"] is True
+    assert cfg.rope_theta == c["rope_theta"] == 999999.4420358813
