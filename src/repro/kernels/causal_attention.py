@@ -9,7 +9,9 @@ are float32.  The forward keeps only the output and the per-row
 logsumexp as residuals; the backward's dq and dk/dv kernels recompute
 the probabilities from them.  Blocks above the diagonal are skipped,
 their compute and their DMA both, so no (S, S) score matrix is ever
-written to HBM.
+written to HBM.  Both residuals carry the checkpoint name
+``ATTN_RESIDUALS``: a ``jax.checkpoint`` whose policy saves that name
+keeps them, and its backward runs no forward kernel again.
 
 The mask is by index: query row i sees keys 0..i.  That is the contract
 ``models.attention.attn_core`` states for row-uniform positions, which
@@ -39,6 +41,9 @@ from jax.experimental.pallas.ops.tpu.splash_attention import (
 BLOCKS = (1024, 512, 256, 128)
 COMPUTE = 512
 
+# the checkpoint name of the forward's output and logsumexp
+ATTN_RESIDUALS = "attn_residuals"
+
 
 def block_size(seq_len: int) -> Optional[int]:
     """The q and kv block of every kernel at ``seq_len``: the largest of
@@ -60,7 +65,8 @@ def _kernel(seq_len: int, group: int, interpret: bool):
     # trace (and mesh) that first asks could not be held by later ones
     with jax.ensure_compile_time_eval():
         kernel = splash.make_splash_mqa_single_device(
-            mask, block_sizes=sizes, interpret=interpret)
+            mask, block_sizes=sizes,
+            residual_checkpoint_name=ATTN_RESIDUALS, interpret=interpret)
     return jax.tree.map(np.asarray, kernel)
 
 

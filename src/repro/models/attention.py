@@ -13,10 +13,14 @@ fused flash attention, forward and backward (``kernels.ops.attention``,
 `repro.kernels.causal_attention`), which computes what ``attn_core``
 would without writing the (Sq, Sk) scores to HBM.  Each call counts
 itself ``attn.fused`` or ``attn.unfused`` (``repro.obs.trace.count``)
-when it is traced.
+when it is traced, and a fused call inside a train step's layer
+checkpoint, which keeps the kernel's output and logsumexp
+(``keeping_residuals``), also ``attn.fwd_saved``.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 from typing import Optional, Tuple
 
 import jax
@@ -30,6 +34,19 @@ from repro.obs import trace
 from repro.perf.knobs import knobs
 
 NEG_INF = -1e30
+
+_KEEPS_RESIDUALS = contextvars.ContextVar("keeps_residuals", default=False)
+
+
+@contextlib.contextmanager
+def keeping_residuals():
+    """Marks the trace of a layer checkpoint whose policy keeps the fused
+    kernel's residuals (``models.model._layer_remat``)."""
+    token = _KEEPS_RESIDUALS.set(True)
+    try:
+        yield
+    finally:
+        _KEEPS_RESIDUALS.reset(token)
 
 
 # ---------------------------------------------------------------------------
@@ -199,6 +216,8 @@ def attention_sp(q, k, v, qpos, *, causal=True, window=0, softcap=0.0,
     fused = _fuses(q.shape[1] // (lay.n_shards if sp else 1), Sk, qpos,
                    kpos, causal=causal, window=window, softcap=softcap)
     trace.count("attn.fused" if fused else "attn.unfused")
+    if fused and _KEEPS_RESIDUALS.get():
+        trace.count("attn.fwd_saved")
     if qpos.ndim == 3:
         qpos = qpos[0]
     if not sp:

@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.dist import sharding as shd
+from repro.kernels.causal_attention import ATTN_RESIDUALS
+from repro.models import attention as A
 from repro.models import layers as L
 from repro.models.blocks import Ctx, LayerSpec, apply_block, cache_struct, init_block
 
@@ -142,6 +144,23 @@ def _norm_cache(c):
     return () if c is None else c
 
 
+_KEEP_ATTN = jax.checkpoint_policies.save_only_these_names(ATTN_RESIDUALS)
+
+
+def _layer_remat(fn):
+    """``fn`` under the train step's layer checkpoint: the backward
+    recomputes the layers' forward, all but the fused attention's output
+    and logsumexp (``ATTN_RESIDUALS``), which it keeps, so the splash
+    forward kernel runs once.  An ``attn_core`` site names nothing: its
+    layer is recomputed whole."""
+    ck = jax.checkpoint(fn, prevent_cse=False, policy=_KEEP_ATTN)
+
+    def run(*args):
+        with A.keeping_residuals():
+            return ck(*args)
+    return run
+
+
 def run_segments(cfg, seg_params, segs, x, ctx: Ctx, caches=None,
                  remat: bool = True):
     """Returns (x, new_caches, aux_total)."""
@@ -159,7 +178,7 @@ def run_segments(cfg, seg_params, segs, x, ctx: Ctx, caches=None,
                     return apply_block(cfg, spec, p_, x_, ctx, cin)
 
                 if remat and ctx.mode == "train":
-                    call = jax.checkpoint(call, prevent_cse=False)
+                    call = _layer_remat(call)
                 x, c, a = call(sp[pi], x)
                 aux_total = aux_total + a
                 ncs.append(_norm_cache(c))
@@ -179,7 +198,7 @@ def run_segments(cfg, seg_params, segs, x, ctx: Ctx, caches=None,
 
             fn = body
             if remat and ctx.mode == "train":
-                fn = jax.checkpoint(body, prevent_cse=False)
+                fn = _layer_remat(body)
             xs = (sp, sc if sc is not None
                   else [() for _ in seg.pattern])
             (x, aux_total), ncs = jax.lax.scan(fn, (x, aux_total), xs)

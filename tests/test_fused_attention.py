@@ -1,9 +1,16 @@
 """The fused causal flash attention behind ``models.attention.attention_sp``
 (interpret mode on CPU): it computes what ``attn_core`` computes, forward
 and gradients; every call it does not qualify for stays on ``attn_core``
-(asserted through the ``attn.*`` counters); and a tiny qwen2-shaped train
-step reads the same loss and gradients through it as through XLA."""
+(asserted through the ``attn.*`` counters); a tiny qwen2-shaped train
+step reads the same loss and gradients through it as through XLA; and the
+train step's layer checkpoint keeps the kernel's output and logsumexp, so
+its backward runs no second forward kernel, alone and under a mesh, while
+an ``attn_core`` layer's remat is unchanged."""
 import dataclasses
+import os
+import re
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -26,13 +33,12 @@ def _qkv(B, S, H, KV, hd, seed=0):
             jax.random.normal(ks[2], (B, S, KV, hd)))
 
 
-def _counted_during(fn):
-    """fn's result and the attn.* counts it added."""
+def _counted_during(fn, names=("attn.fused", "attn.unfused")):
+    """fn's result and the counts of ``names`` it added."""
     before = trace.counted()
     out = fn()
     after = trace.counted()
-    return out, {n: after.get(n, 0) - before.get(n, 0)
-                 for n in ("attn.fused", "attn.unfused")}
+    return out, {n: after.get(n, 0) - before.get(n, 0) for n in names}
 
 
 @pytest.mark.parametrize("window", ["0", "S"])
@@ -149,3 +155,95 @@ def test_tiny_train_step_interpret_matches_xla(monkeypatch):
         scale = float(jnp.max(jnp.abs(gx)))
         np.testing.assert_allclose(gi, gx, atol=1e-4 * scale + 1e-7,
                                    rtol=1e-3, err_msg=str(path))
+
+
+def _pallas_calls(jaxpr, names=None):
+    """The names of every pallas_call in ``jaxpr`` and its sub-jaxprs."""
+    names = [] if names is None else names
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (list, tuple)) else (p,):
+                sub = getattr(sub, "jaxpr", sub)      # a ClosedJaxpr's
+                if hasattr(sub, "eqns"):
+                    _pallas_calls(sub, names)
+    return names
+
+
+def _step_jaxpr(cfg, S=256, B=2):
+    params = M.init_model(cfg, jax.random.PRNGKey(0))
+    tokens = jnp.zeros((B, S), jnp.int32)
+    batch = {"tokens": tokens, "labels": tokens,
+             "positions": jnp.broadcast_to(jnp.arange(S)[None], (B, S))}
+    opt = optim.sgd(0.1)
+    return jax.make_jaxpr(make_train_step(cfg, opt))(
+        {"params": params, "opt": opt.init(params)}, batch)
+
+
+@pytest.mark.parametrize("keep,forwards", [(True, 1), (False, 2)])
+@pytest.mark.parametrize("n_layers", [1, 2])
+def test_layer_checkpoint_runs_the_forward_kernel_once(monkeypatch, n_layers,
+                                                       keep, forwards):
+    """The train step's layer checkpoint (scanned at 2 layers, one call at
+    1) keeps the kernel's output and logsumexp, so its gradient holds one
+    splash forward per layer body, where a checkpoint without the policy
+    holds two: the forward's and the backward's recompute."""
+    monkeypatch.setattr(ops, "KERNEL_BACKEND", "interpret")
+    if not keep:
+        monkeypatch.setattr(M, "_KEEP_ATTN", None)
+    cfg = dataclasses.replace(tiny_qwen2(), n_layers=n_layers)
+    jaxpr, counts = _counted_during(
+        lambda: _step_jaxpr(cfg),
+        ("attn.fused", "attn.unfused", "attn.fwd_saved"))
+    names = _pallas_calls(jaxpr.jaxpr)
+    assert names.count("splash_mqa_fwd_residuals") == forwards
+    assert names.count("splash_mqa_dq_no_residuals") == 1
+    assert names.count("splash_mqa_dkv_no_residuals") == 1
+    assert counts["attn.fused"] == 1 and counts["attn.unfused"] == 0
+    if keep:
+        assert counts["attn.fwd_saved"] == 1
+
+
+def test_kept_residuals_leave_the_gradients(monkeypatch):
+    """The backward pairs recomputed q/k/v with the forward's kept output
+    and logsumexp: the same loss and gradients as a full recompute."""
+    monkeypatch.setattr(ops, "KERNEL_BACKEND", "interpret")
+    cfg = tiny_qwen2()
+    loss_k, g_k = tiny_step(cfg)
+    monkeypatch.setattr(M, "_KEEP_ATTN", None)
+    loss_r, g_r = tiny_step(cfg)
+    assert loss_k == pytest.approx(loss_r, rel=1e-6)
+    leaves_r = jax.tree.leaves_with_path(g_r)
+    for (path, gr), gk in zip(leaves_r, jax.tree.leaves(g_k)):
+        scale = float(jnp.max(jnp.abs(gr)))
+        np.testing.assert_allclose(gk, gr, atol=1e-6 * scale + 1e-9,
+                                   rtol=1e-5, err_msg=str(path))
+
+
+@pytest.mark.parametrize("n_layers", [1, 2])
+def test_attn_core_remat_is_unchanged(monkeypatch, n_layers):
+    """attn_core names nothing, so the policy keeps nothing: the gradient's
+    jaxpr is the full recompute's, equation for equation."""
+    monkeypatch.setattr(ops, "KERNEL_BACKEND", "xla")
+    cfg = dataclasses.replace(tiny_qwen2(), n_layers=n_layers)
+    policy = re.compile(r"policy=[^\n]*")
+    kept = policy.sub("policy", str(_step_jaxpr(cfg)))
+    monkeypatch.setattr(M, "_KEEP_ATTN", None)
+    full = policy.sub("policy", str(_step_jaxpr(cfg)))
+    assert kept == full
+    assert "pallas_call" not in kept
+
+
+def test_layer_checkpoint_keeps_residuals_through_shard_map():
+    """On a 4-device host mesh the kernel runs in ``shard_map`` over the
+    batch; the checkpoint name survives it (payload in its own process:
+    the device count is fixed when JAX starts)."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"))
+    r = subprocess.run(
+        [sys.executable, os.path.join(root, "tests", "sharded",
+                                      "fused_remat_check.py")],
+        capture_output=True, text=True, env=env, timeout=600)
+    assert r.returncode == 0 and "FAIL" not in r.stdout, (
+        f"\nSTDOUT:{r.stdout}\nSTDERR:{r.stderr[-2000:]}")

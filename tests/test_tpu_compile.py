@@ -6,6 +6,7 @@ one process at a time may load the TPU library, and test workers import
 every test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +20,7 @@ from repro.core.runtime_model.api import RuntimeModel
 from repro.kernels.causal_attention import causal_attention
 from repro.kernels.fused_adam import fused_adam
 from repro.kernels.masked_grad_agg import masked_grad_agg
+from repro.models import model as M
 
 
 @pytest.fixture(scope="module")
@@ -87,6 +89,53 @@ def test_causal_attention_compiles_forward_and_backward(one_chip, heads):
     hlo = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv).compile(
     ).as_text()
     assert hlo.count("tpu_custom_call") == 3     # forward, dq, dk/dv
+
+
+def _kernels_by_computation(hlo):
+    """{computation: [custom-call instruction names]} of an HLO module."""
+    out, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(", line)
+        if head:
+            name = head.group(1)
+            out[name] = []
+        elif name and " custom-call(" in line:
+            out[name].append(line.split("=", 1)[0].strip().lstrip("%"))
+    return out
+
+
+@pytest.mark.parametrize("heads", [(14, 2, 64), (24, 2, 128)])
+def test_layer_checkpoint_keeps_forward_kernel_out_of_backward(one_chip,
+                                                               heads):
+    # two layers of the train step's checkpointed scan over the kernel at
+    # qwen2-0.5b's and starcoder2-3b's heads, seq 4096 x batch 8
+    H, KV, hd = heads
+    L, B, S = 2, 8, 4096
+
+    def loss(x, ws):
+        def body(x, w):
+            return x + causal_attention(x * w, x[:, :, :KV],
+                                        x[:, :, KV:2 * KV]), None
+        x, _ = jax.lax.scan(M._layer_remat(body), x, ws)
+        return jnp.sum(x.astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, (0, 1))).lower(
+        _spec((B, S, H, hd), jnp.bfloat16, one_chip),
+        _spec((L, H, hd), jnp.bfloat16, one_chip)).compile()
+    hlo = compiled.as_text()
+    bodies = re.findall(r" while\(.*body=%([\w.\-]+)", hlo)
+    where = {kind: [c for c, calls in _kernels_by_computation(hlo).items()
+                    for k in calls if k.startswith(f"splash_mqa_{kind}")]
+             for kind in ("fwd", "dq", "dkv")}
+    # one forward kernel, in one loop; the backward's loop holds the
+    # dq and dk/dv kernels and no forward
+    assert len(where["fwd"]) == 1 and len(where["dq"]) == 1
+    assert where["dq"] == where["dkv"]
+    assert where["fwd"][0] in bodies and where["dq"][0] in bodies
+    assert where["fwd"] != where["dq"]
+    # the kept output (bf16) and logsumexp (f32) of every layer
+    kept = L * B * S * H * (2 * hd + 4)
+    assert compiled.memory_analysis().temp_size_in_bytes >= kept
 
 
 def test_fused_observe_decide_compiles_at_158(one_chip):
